@@ -85,8 +85,9 @@ def run_bench(args) -> dict:
           f"p99={load.get('upload_p99_s', float('nan')):.4f}s "
           f"infer_p50={load.get('infer_p50_s', float('nan')):.4f}s "
           f"rejections={load['rejections']}")
+    # the parent never initializes a JAX backend: the server process is
+    # the only one that may hold the accelerator (launch/federate_load.py)
     return {"setup": {"jax": jax.__version__,
-                      "device_count": jax.device_count(),
                       "quick": bool(args.quick), "sweeps": sweeps,
                       "anchor_sweeps": anchor_sweeps, "procs": procs,
                       **size},

@@ -6,7 +6,7 @@ from repro.core.engine import FederationEngine, combine_arrivals  # noqa: F401,E
 from repro.core.transforms import (  # noqa: F401
     TRANSFORMS, TransformCtx, build_transforms)
 from repro.core.protocol import (  # noqa: F401
-    ClientState, FedAvgTrainer, FederatedTrainer, client_round_update,
+    ClientState, FedAvgTrainer, FederatedTrainer,
     make_federated_train_step, param_delta, train_centralized,
     train_non_collaborative, weighted_global_loss)
 from repro.core.rounds import RoundEngine, RoundScheduler  # noqa: F401

@@ -139,6 +139,70 @@ def secure_mask_grads(grads: Pytree, round_key, client: int,
 # ---------------------------------------------------------------------------
 # top-k sparsification + error feedback
 # ---------------------------------------------------------------------------
+def bf16_magnitude_key(x):
+    """int32 rank key of ``|x|`` rounded to the bf16 grid.
+
+    The bits of a non-negative float32 order like the float itself, so
+    rounding them to the upper 16 (round-to-nearest-even, the rounding
+    of an fp32 -> bf16 cast) gives an integer key in ``[0, 0x8000]``
+    whose order is the bf16-quantized magnitude's.  Integer arithmetic
+    keeps the quantization exact under any compiler: no backend may
+    elide it as excess float precision.
+    """
+    bits = jax.lax.bitcast_convert_type(
+        jnp.abs(x.astype(jnp.float32)), jnp.int32)
+    q = bits >> 16
+    rem = bits & 0xFFFF
+    up = (rem > 0x8000) | ((rem == 0x8000) & ((q & 1) == 1))
+    return q + up.astype(jnp.int32)
+
+
+_KEY_END = 0x8001      # one past the largest bf16_magnitude_key
+
+
+def topk_keep_from_keys(key, idx, k: int, n: int, count):
+    """Mask of the ``k`` largest ``key`` entries, ties toward lower ``idx``.
+
+    ``count(pred)`` sums a boolean array over the selection axes with
+    kept dims; ``idx`` is each entry's position (``0 <= idx < n``).
+    Entries with a negative key are padding: never counted, never kept.
+    Two bisections replace a sort, so the rule lowers anywhere a
+    compare and a sum do (XLA and the Pallas kernel alike):
+
+    * the threshold ``t`` is the largest key with ``count(key >= t) >=
+      k`` — the k-th largest key;
+    * of the entries tied at ``t``, the first ``k - count(key > t)`` by
+      index are kept: ``j`` is the smallest bound with that many ties
+      below it.
+    """
+    total = count(key >= 0)
+    shape = total.shape
+    need_k = jnp.asarray(k, total.dtype)
+
+    def key_step(_, lohi):
+        lo, hi = lohi
+        mid = (lo + hi) >> 1
+        ok = count(key >= mid) >= need_k
+        return jnp.where(ok, mid, lo), jnp.where(ok, hi, mid)
+
+    lo = jnp.zeros(shape, jnp.int32)
+    t, _ = jax.lax.fori_loop(0, _KEY_END.bit_length(), key_step,
+                             (lo, jnp.full(shape, _KEY_END, jnp.int32)))
+    greater = key > t
+    tie = key == t
+    need = need_k - count(greater)
+
+    def idx_step(_, lohi):
+        lo_j, hi_j = lohi
+        mid = (lo_j + hi_j) >> 1
+        ok = count(tie & (idx < mid)) >= need
+        return jnp.where(ok, lo_j, mid), jnp.where(ok, mid, hi_j)
+
+    _, j = jax.lax.fori_loop(0, max(int(n), 1).bit_length(), idx_step,
+                             (lo, jnp.full(shape, n, jnp.int32)))
+    return greater | (tie & (idx < j))
+
+
 def topk_keep_mask(mag, k: int):
     """Boolean mask keeping EXACTLY the ``k`` largest entries of the last
     axis, ranked on bf16-QUANTIZED magnitude with ties broken
@@ -152,27 +216,30 @@ def topk_keep_mask(mag, k: int):
 
     * lexicographic (magnitude desc, index asc) ranking keeps exactly
       ``k`` entries and resolves EXACT ties identically on every path;
-    * ranking on the bf16 rounding of ``mag`` (compare in fp32 after a
-      round-trip cast) collapses NEAR-ties — coordinates whose fp32
-      magnitudes differ by less than the ~2^-8 relative bf16 grid — into
-      exact ties, so sub-grid perturbations from cross-path reduction
-      order cannot reorder the ranking.  A flip now requires the
-      perturbation to push a magnitude across a bf16 grid boundary.
+    * ranking on the bf16 rounding of ``mag``
+      (:func:`bf16_magnitude_key`) collapses NEAR-ties — coordinates
+      whose fp32 magnitudes differ by less than the ~2^-8 relative bf16
+      grid — into exact ties.
 
-    The quantization affects only WHICH coordinates are kept among
+    A perturbation that pushes a magnitude across a bf16 grid boundary
+    still flips the choice, so paths held to one trajectory must hand
+    this function bitwise-equal inputs (the engine's loop and vmap
+    paths run one local-update function for that reason).  The
+    quantization affects only WHICH coordinates are kept among
     near-equals (immaterial under error feedback — the residual of a
     skipped coordinate transmits next round); kept values are sent at
     full precision.  Shared by :func:`topk_sparsify` and the fused
     Pallas kernel (``kernels/fed_aggregate.py``) — one selection rule,
-    every backend.
+    every backend; ``kernels/ref.py`` holds a sort-based oracle.
     """
-    magq = mag.astype(jnp.bfloat16).astype(jnp.float32)
-    thresh = jax.lax.top_k(magq, k)[0][..., -1:]
-    greater = magq > thresh
-    n_greater = jnp.sum(greater, axis=-1, keepdims=True)
-    tie = magq == thresh
-    tie_rank = jnp.cumsum(tie.astype(jnp.int32), axis=-1) - 1
-    return greater | (tie & (tie_rank < k - n_greater))
+    key = bf16_magnitude_key(mag)
+    n = mag.shape[-1]
+    idx = jax.lax.broadcasted_iota(jnp.int32, mag.shape, mag.ndim - 1)
+
+    def count(pred):
+        return jnp.sum(pred, axis=-1, keepdims=True, dtype=jnp.int32)
+
+    return topk_keep_from_keys(key, idx, k, n, count)
 
 
 def topk_sparsify(tree: Pytree, frac: float) -> Pytree:

@@ -42,7 +42,7 @@ from repro.core import aggregation as agg
 # (`from repro.core.protocol import ClientState, ...`) keeps working
 from repro.core.engine import (  # noqa: F401
     EXEC_MODES, ClientState, FederationEngine, _check_vmap_preconditions,
-    _rel_change, client_round_update, masked_mean_loss, param_delta)
+    _rel_change, masked_mean_loss, param_delta)
 from repro.optim.optimizers import Optimizer, sgd
 
 Pytree = Any
@@ -78,9 +78,6 @@ def make_federated_train_step(
     along the client axes is one federated client N_l.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
-
-    from repro.parallel.sharding import axis_size
 
     fed = fed or FederatedConfig()
     axis = client_axes if len(client_axes) > 1 else client_axes[0]
@@ -93,10 +90,10 @@ def make_federated_train_step(
             cid = jax.lax.axis_index(client_axes[0])
             if len(client_axes) > 1:
                 for ax in client_axes[1:]:
-                    cid = cid * axis_size(ax) + jax.lax.axis_index(ax)
+                    cid = cid * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
             num_clients = 1
             for ax in client_axes:
-                num_clients *= axis_size(ax)
+                num_clients *= jax.lax.axis_size(ax)
             local_rng = jax.random.fold_in(rng, cid)
             lbatch = dict(batch)
             if "rng" in lbatch:
@@ -128,11 +125,11 @@ def make_federated_train_step(
             return new_params, new_opt, mean_loss
 
         batch_specs = jax.tree_util.tree_map(lambda _: P(axis), batch)
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(), batch_specs, P(), P()),
             out_specs=(P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(params, opt_state, batch, step_idx, rng)
 
     return step

@@ -1,7 +1,7 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to auto-detect: False on real TPU backends, True on
-CPU (this container) where the kernel body executes in Python for
+``interpret`` defaults to what the platform allows: False on TPU
+backends, True elsewhere, where the kernel body executes in Python for
 validation.  Model code imports from here, never from the kernel modules.
 """
 from __future__ import annotations
@@ -10,7 +10,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 # defined BEFORE the repro.core import below: core/__init__ -> engine
@@ -82,8 +81,8 @@ def topic_decoder_loss(theta, beta, bow, dec_scale=None, *,
 # cross-device Eq. (2) reduction is one psum of the per-device partial
 # numerators (DESIGN.md §5: per-device partials of the secure-mask stack
 # stay on the dyadic grid, so the psum order cannot break cancellation).
-# ``check_rep=False`` everywhere a pallas_call sits inside the island —
-# the pinned jax has no replication rule for pallas_call.
+# ``check_vma=False`` everywhere a pallas_call sits inside the island —
+# jax has no varying-manual-axes rule for pallas_call.
 # ---------------------------------------------------------------------------
 def _check_backend(backend: str) -> None:
     if backend not in KERNEL_BACKENDS:
@@ -95,6 +94,17 @@ def _check_backend(backend: str) -> None:
 def _flat2(leaf):
     """Stacked leaf (K, ...) -> (K, D) without copying when already 2-D."""
     return leaf.reshape((leaf.shape[0], -1))
+
+
+def _coef_matvec(c, leaf):
+    """``sum_k c_k x_k`` of a stacked leaf as one matvec over its
+    flattened rows (no ring-sized temporary).  HIGHEST precision states
+    the fp32 contract: at the TPU's default precision XLA may run an
+    fp32 matmul as bf16 passes on the MXU (a 15-slot ring measured the
+    same at both precisions)."""
+    return jnp.matmul(c, _flat2(leaf).astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST
+                      ).reshape(leaf.shape[1:])
 
 
 def _local_weighted_num(tree, w, backend: str, interpret: bool):
@@ -148,9 +158,9 @@ def fed_weighted_combine(tree, weights, *, backend: str = "xla",
         total = jnp.maximum(jax.lax.psum(jnp.sum(w32), "data"), 1e-12)
         return jax.tree_util.tree_map(lambda n: n / total, num)
 
-    return shard_map(local, mesh=mesh, in_specs=(P("data"), P("data")),
-                     out_specs=P(), check_rep=False)(
-                         tree, jnp.asarray(weights, jnp.float32))
+    return jax.shard_map(local, mesh=mesh, in_specs=(P("data"), P("data")),
+                         out_specs=P(), check_vma=False)(
+                             tree, jnp.asarray(weights, jnp.float32))
 
 
 def fed_weighted_sum(tree, coefs, *, backend: str = "xla",
@@ -165,8 +175,7 @@ def fed_weighted_sum(tree, coefs, *, backend: str = "xla",
     if mesh is None:
         if backend == "xla":
             return jax.tree_util.tree_map(
-                lambda leaf: (c @ _flat2(leaf).astype(jnp.float32))
-                .reshape(leaf.shape[1:]), tree)
+                lambda leaf: _coef_matvec(c, leaf), tree)
         interpret = _auto_interpret() if interpret is None else interpret
         return jax.tree_util.tree_map(
             lambda leaf: fed_weighted_sum_pallas(
@@ -179,8 +188,7 @@ def fed_weighted_sum(tree, coefs, *, backend: str = "xla",
     def local(tree_l, c_l):
         if backend == "xla":
             num = jax.tree_util.tree_map(
-                lambda leaf: (c_l @ _flat2(leaf).astype(jnp.float32))
-                .reshape(leaf.shape[1:]), tree_l)
+                lambda leaf: _coef_matvec(c_l, leaf), tree_l)
         else:
             num = jax.tree_util.tree_map(
                 lambda leaf: fed_weighted_sum_pallas(
@@ -189,8 +197,8 @@ def fed_weighted_sum(tree, coefs, *, backend: str = "xla",
         return jax.tree_util.tree_map(lambda x: jax.lax.psum(x, "data"),
                                       num)
 
-    return shard_map(local, mesh=mesh, in_specs=(P("data"), P("data")),
-                     out_specs=P(), check_rep=False)(tree, c)
+    return jax.shard_map(local, mesh=mesh, in_specs=(P("data"), P("data")),
+                         out_specs=P(), check_vma=False)(tree, c)
 
 
 def fed_topk_ef(msgs, err_state, ids, *, frac: float, backend: str = "xla",
@@ -205,70 +213,44 @@ def fed_topk_ef(msgs, err_state, ids, *, frac: float, backend: str = "xla",
     ``new_err`` back into the ``(L, ...)`` state (padded rows dropped)
     stays with the caller.
 
-    With ``mesh`` (K and L both row-sharded over ``"data"``), the
-    cohort's error rows are gathered OUTSIDE the island — GSPMD lowers
-    ``err[ids]`` into the cross-shard collective — and each device runs
-    the per-row correct/top-k/residual kernel on its own pre-gathered
-    rows with iota ids.  Same math: ``corrected = msg + err[ids]`` row
-    by row, no cross-row term anywhere.
+    The cohort's error rows are gathered before the per-row math (with
+    ``mesh``, K and L both row-sharded over ``"data"``, GSPMD lowers
+    ``err[ids]`` into the cross-shard collective and each device then
+    runs the per-row correct/top-k/residual on its own rows).  Same
+    math everywhere: ``corrected = msg + err[ids]`` row by row, no
+    cross-row term anywhere.
     """
     _check_backend(backend)
     ids = jnp.asarray(ids, jnp.int32)
+    itp = _auto_interpret() if interpret is None else interpret
+    gathered = jax.tree_util.tree_map(lambda e: e[ids], err_state)
 
-    if mesh is not None:
-        itp = _auto_interpret() if interpret is None else interpret
-        gathered = jax.tree_util.tree_map(lambda e: e[ids], err_state)
+    def rows(msgs_l, err_l):
+        def one_leaf(m, e):
+            m2, e2 = _flat2(m), _flat2(e)
+            k_keep = max(int(frac * m2.shape[1]), 1)
+            if backend == "xla":
+                corrected = m2.astype(jnp.float32) + e2.astype(jnp.float32)
+                mask = topk_keep_mask(jnp.abs(corrected), k_keep)
+                sent = jnp.where(mask, corrected, 0.0)
+                new_err = corrected - sent
+            else:
+                sent, new_err = fed_topk_ef_pallas(m2, e2, k_keep=k_keep,
+                                                   interpret=itp)
+            return sent.reshape(m.shape), new_err.reshape(m.shape)
 
-        def local(msgs_l, err_l):
-            def one_leaf(m, e):
-                m2, e2 = _flat2(m), _flat2(e)
-                k_keep = max(int(frac * m2.shape[1]), 1)
-                if backend == "xla":
-                    corrected = m2.astype(jnp.float32) \
-                        + e2.astype(jnp.float32)
-                    mask = topk_keep_mask(jnp.abs(corrected), k_keep)
-                    sent = jnp.where(mask, corrected, 0.0)
-                    new_err = corrected - sent
-                else:
-                    iota = jnp.arange(m2.shape[0], dtype=jnp.int32)
-                    sent, new_err = fed_topk_ef_pallas(
-                        m2, e2, iota, k_keep=k_keep, interpret=itp)
-                return sent.reshape(m.shape), new_err.reshape(m.shape)
+        pairs = jax.tree_util.tree_map(one_leaf, msgs_l, err_l)
+        is_pair = lambda x: isinstance(x, tuple)  # noqa: E731
+        return (jax.tree_util.tree_map(lambda p: p[0], pairs,
+                                       is_leaf=is_pair),
+                jax.tree_util.tree_map(lambda p: p[1], pairs,
+                                       is_leaf=is_pair))
 
-            pairs = jax.tree_util.tree_map(one_leaf, msgs_l, err_l)
-            is_pair = lambda x: isinstance(x, tuple)  # noqa: E731
-            return (jax.tree_util.tree_map(lambda p: p[0], pairs,
-                                           is_leaf=is_pair),
-                    jax.tree_util.tree_map(lambda p: p[1], pairs,
-                                           is_leaf=is_pair))
-
-        return shard_map(local, mesh=mesh,
-                         in_specs=(P("data"), P("data")),
+    if mesh is None:
+        return rows(msgs, gathered)
+    return jax.shard_map(rows, mesh=mesh, in_specs=(P("data"), P("data")),
                          out_specs=(P("data"), P("data")),
-                         check_rep=False)(msgs, gathered)
-
-    def one_leaf(msg_leaf, err_leaf):
-        m2 = _flat2(msg_leaf)
-        e2 = _flat2(err_leaf)
-        k_keep = max(int(frac * m2.shape[1]), 1)
-        if backend == "xla":
-            corrected = m2.astype(jnp.float32) + e2[ids].astype(jnp.float32)
-            mask = topk_keep_mask(jnp.abs(corrected), k_keep)
-            sent = jnp.where(mask, corrected, 0.0)
-            new_err = corrected - sent
-        else:
-            itp = _auto_interpret() if interpret is None else interpret
-            sent, new_err = fed_topk_ef_pallas(m2, e2, ids, k_keep=k_keep,
-                                               interpret=itp)
-        return (sent.reshape(msg_leaf.shape),
-                new_err.reshape(msg_leaf.shape))
-
-    pairs = jax.tree_util.tree_map(one_leaf, msgs, err_state)
-    sent = jax.tree_util.tree_map(lambda p: p[0], pairs,
-                                  is_leaf=lambda x: isinstance(x, tuple))
-    new_err = jax.tree_util.tree_map(lambda p: p[1], pairs,
-                                     is_leaf=lambda x: isinstance(x, tuple))
-    return sent, new_err
+                         check_vma=False)(msgs, gathered)
 
 
 def fed_dp_secure_apply(tree, *, noise=None, masks=None, clip_coef=None,
@@ -303,8 +285,8 @@ def fed_dp_secure_apply(tree, *, noise=None, masks=None, clip_coef=None,
                 interpret=interpret, mesh=None)
 
         specs = {k: P("data") for k in packed}
-        return shard_map(local, mesh=mesh, in_specs=(specs,),
-                         out_specs=P("data"), check_rep=False)(packed)
+        return jax.shard_map(local, mesh=mesh, in_specs=(specs,),
+                             out_specs=P("data"), check_vma=False)(packed)
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     noise_leaves = (jax.tree_util.tree_leaves(noise) if noise is not None
                     else [None] * len(leaves))

@@ -91,11 +91,26 @@ def fed_topk_ef_ref(msgs, err_rows, k_keep: int):
     ``core.aggregation.topk_keep_mask``);  new_err = corrected - sent.
     Returns ``(sent, new_err)``, both ``(K, D)`` fp32.
     """
-    from repro.core.aggregation import topk_keep_mask
     corrected = msgs.astype(jnp.float32) + err_rows.astype(jnp.float32)
-    mask = topk_keep_mask(jnp.abs(corrected), k_keep)
-    sent = jnp.where(mask, corrected, 0.0)
+    sent = jnp.where(topk_mask_ref(corrected, k_keep), corrected, 0.0)
     return sent, corrected - sent
+
+
+def topk_mask_ref(x, k: int):
+    """The ``k`` largest bf16-rounded ``|x|`` of the last axis, ties
+    toward the lower index — sort-based (``lax.top_k`` for the
+    threshold, a cumulative tie rank for the index rule), independent of
+    the bisection ``core.aggregation.topk_keep_mask`` and the Pallas
+    kernel share, so parity against it checks that rule rather than
+    restating it."""
+    from repro.core.aggregation import bf16_magnitude_key
+    key = bf16_magnitude_key(x)
+    thresh = jax.lax.top_k(key, k)[0][..., -1:]
+    greater = key > thresh
+    tie = key == thresh
+    tie_rank = jnp.cumsum(tie.astype(jnp.int32), axis=-1) - 1
+    return greater | (tie & (tie_rank < k - jnp.sum(
+        greater, axis=-1, keepdims=True)))
 
 
 def fed_dp_secure_apply_ref(msgs, noise=None, masks=None, clip_coef=None,

@@ -43,8 +43,7 @@ from repro.models import transformer as tfm
 from repro.optim.optimizers import get_optimizer
 from repro.parallel.sharding import (batch_partition_spec,
                                      cache_partition_specs,
-                                     param_partition_specs, shardings_for,
-                                     use_abstract_mesh)
+                                     param_partition_specs, shardings_for)
 
 
 def _model_flops(cfg, shape) -> float:
@@ -129,7 +128,7 @@ def lower_pair(arch: str, shape_name: str, mesh, *, optimizer="sgd",
         at layer boundaries, not inside chunk scans), extrapolated
         linearly to the real depth.
     """
-    with use_abstract_mesh(mesh):
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
         shape = get_shape(shape_name)
         cfg = resolve_arch_for_shape(get_config(arch), shape)
         if mla_absorb:

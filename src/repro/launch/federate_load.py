@@ -17,10 +17,18 @@ Two regimes:
 
 * ``run_load`` — the concurrent measurement (>= 4 processes in CI).
 * ``run_anchor`` — the sync-equivalence anchor OVER THE WIRE: M=K,
-  staleness 0, in-order sequential uploads from the parent; the final
-  ``GET /v1/model`` params must match the sync twin's
+  staleness 0, in-order sequential uploads from one client process;
+  the final ``GET /v1/model`` params must match the sync twin's
   ``Federation.run()`` within 1e-5 (DESIGN.md §6 — the same anchor the
-  in-process tests pin, now crossing encode → TCP → decode).
+  in-process tests pin, now crossing encode → TCP → decode).  Server,
+  client and twin all run on the CPU backend, so the comparison is of
+  like with like.
+
+One process per accelerator: the parent never initializes a JAX
+backend (it only spawns, collects, and talks plain HTTP to the
+server); the load cell's server is the only process on the default
+backend, and every client process — a stand-in for a remote device —
+is spawned with ``JAX_PLATFORMS=cpu``.
 
 Usage:
 
@@ -40,7 +48,7 @@ import multiprocessing as mp
 import os
 import sys
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List
 
 import numpy as np
 
@@ -55,9 +63,11 @@ def _server_main(spec_dict: Dict[str, Any], conn) -> None:
     """Server process: build the service from the spec dict and serve
     until a wire-side shutdown; the bound (host, port) goes back first."""
     from repro.api.spec import FederationSpec
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.net.server import run_server
     from repro.serve import FederationService
 
+    enable_compile_cache()
     spec = FederationSpec.from_dict(spec_dict)
     service = FederationService.from_spec(spec)
     run_server(service, on_bound=lambda h, p: conn.send((h, p)))
@@ -113,9 +123,95 @@ def _client_main(spec_dict: Dict[str, Any], host: str, port: int,
         client.close()
 
 
+def _anchor_client_main(spec_dict: Dict[str, Any], host: str, port: int,
+                        sweeps: int, conn) -> None:
+    """Anchor client process: in-order uploads over the wire, then the
+    sync twin's ``Federation.run()`` on this same (CPU) backend and the
+    deviation of the wire model from it."""
+    from repro.api.federation import Federation, max_param_dev
+    from repro.api.spec import FederationSpec
+    from repro.net.client import ServiceClient
+    from repro.serve import sync_twin_spec
+
+    spec = FederationSpec.from_dict(spec_dict)
+    client = ServiceClient(spec, host, port)
+    try:
+        upload_lat: List[float] = []
+        accepted = 0
+        for _sweep in range(int(sweeps)):
+            for c in range(spec.data.num_clients):
+                bv, delta, w = client.client_update(c)
+                t0 = time.perf_counter()
+                receipt = client.submit(c, delta, w, base_version=bv)
+                upload_lat.append(time.perf_counter() - t0)
+                accepted += int(receipt["accepted"])
+        version, wire_params = client.fetch_model()
+        twin = Federation.from_spec(sync_twin_spec(spec))
+        twin.run()
+        conn.send({"ok": True, "accepted": accepted, "version": version,
+                   "upload_lat": upload_lat,
+                   "final_param_dev": float(max_param_dev(
+                       twin.engine.params, wire_params))})
+    except Exception as e:              # surfaced by the parent
+        conn.send({"ok": False, "error": f"{type(e).__name__}: {e}"})
+        raise
+    finally:
+        client.close()
+
+
 # ---------------------------------------------------------------------------
-# parent-side drivers
+# parent-side drivers (no JAX backend here: spawn, collect, plain HTTP)
 # ---------------------------------------------------------------------------
+def _spawn(ctx, target, args, *, cpu: bool):
+    """Start ``target(*args, child_conn)`` in a spawned process; ``cpu``
+    spawns it with ``JAX_PLATFORMS=cpu`` in its environment, so it never
+    contends for the accelerator (the parent's own environment is
+    restored).  Returns ``(process, parent_conn)``."""
+    parent_conn, child_conn = ctx.Pipe()
+    proc = ctx.Process(target=target, args=(*args, child_conn), daemon=True)
+    saved = os.environ.get("JAX_PLATFORMS")
+    if cpu:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        proc.start()
+    finally:
+        if saved is None:
+            os.environ.pop("JAX_PLATFORMS", None)
+        else:
+            os.environ["JAX_PLATFORMS"] = saved
+    return proc, parent_conn
+
+
+def _wire_json(host: str, port: int, method: str, path: str
+               ) -> Dict[str, Any]:
+    """One JSON call to the server over plain HTTP (the parent's status
+    probe and shutdown — no local replica, no JAX backend)."""
+    from repro.net.client import HttpClient
+    http = HttpClient(host, port)
+    try:
+        status, resp = http.request_with_retry(method, path)
+    finally:
+        http.close()
+    out = json.loads(resp.decode("utf-8"))
+    if status != 200:
+        raise RuntimeError(f"{method} {path} answered {status}: {out}")
+    return out
+
+
+def _collect(proc, conn, server, timeout: float) -> Dict[str, Any]:
+    """A child's result, or the server torn down and an error raised."""
+    proc.join(timeout)
+    if not conn.poll(1):
+        server.terminate()
+        raise RuntimeError(f"child process pid={proc.pid} died without a "
+                           f"result (exitcode {proc.exitcode})")
+    r = conn.recv()
+    if not r.get("ok"):
+        server.terminate()
+        raise RuntimeError(f"child process failed: {r.get('error')}")
+    return r
+
+
 def _percentiles(lat: List[float], prefix: str) -> Dict[str, float]:
     if not lat:
         return {}
@@ -125,11 +221,8 @@ def _percentiles(lat: List[float], prefix: str) -> Dict[str, float]:
             f"{prefix}_p99_s": float(np.percentile(arr, 99))}
 
 
-def _boot_server(ctx, spec_dict: Dict[str, Any]):
-    parent_conn, child_conn = ctx.Pipe()
-    proc = ctx.Process(target=_server_main, args=(spec_dict, child_conn),
-                       daemon=True)
-    proc.start()
+def _boot_server(ctx, spec_dict: Dict[str, Any], *, cpu: bool = False):
+    proc, parent_conn = _spawn(ctx, _server_main, (spec_dict,), cpu=cpu)
     if not parent_conn.poll(SERVER_BOOT_TIMEOUT_S):
         proc.terminate()
         raise RuntimeError(
@@ -142,8 +235,6 @@ def run_load(spec, *, procs: int, sweeps: int, infer_every: int = 4,
              infer_batch: int = 8, order_seed: int = 0) -> Dict[str, Any]:
     """The concurrent cell: ``procs`` client processes over a round-robin
     shard of the population.  Returns the ``wire-load`` stats dict."""
-    from repro.net.client import ServiceClient
-
     if procs < 1:
         raise ValueError(f"procs must be >= 1, got {procs}")
     L = spec.data.num_clients
@@ -155,35 +246,17 @@ def run_load(spec, *, procs: int, sweeps: int, infer_every: int = 4,
     server, host, port = _boot_server(ctx, spec_dict)
     shards = [list(range(L))[i::procs] for i in range(procs)]
     t0 = time.perf_counter()
-    workers = []
-    for i, shard in enumerate(shards):
-        parent_conn, child_conn = ctx.Pipe()
-        p = ctx.Process(target=_client_main,
-                        args=(spec_dict, host, port, shard, sweeps,
-                              order_seed * 1000 + i, infer_every,
-                              infer_batch, child_conn),
-                        daemon=True)
-        p.start()
-        workers.append((p, parent_conn))
-    results = []
-    for p, conn in workers:
-        p.join(CLIENT_JOIN_TIMEOUT_S)
-        if not conn.poll(1):
-            server.terminate()
-            raise RuntimeError(
-                f"client process pid={p.pid} died without a result "
-                f"(exitcode {p.exitcode})")
-        r = conn.recv()
-        if not r.get("ok"):
-            server.terminate()
-            raise RuntimeError(f"client process failed: {r.get('error')}")
-        results.append(r)
+    workers = [
+        _spawn(ctx, _client_main,
+               (spec_dict, host, port, shard, sweeps, order_seed * 1000 + i,
+                infer_every, infer_batch), cpu=True)
+        for i, shard in enumerate(shards)]
+    results = [_collect(p, conn, server, CLIENT_JOIN_TIMEOUT_S)
+               for p, conn in workers]
     wall = time.perf_counter() - t0
     # authoritative server-side view, then a wire shutdown
-    probe = ServiceClient(spec, host, port)
-    status = probe.status()
-    probe.shutdown(drain=True)
-    probe.close()
+    status = _wire_json(host, port, "GET", "/v1/status")
+    _wire_json(host, port, "POST", "/v1/shutdown?drain=true")
     server.join(60)
     upload_lat = [x for r in results for x in r["upload_lat"]]
     infer_lat = [x for r in results for x in r["infer_lat"]]
@@ -208,43 +281,28 @@ def run_anchor(spec, *, sweeps: int) -> Dict[str, Any]:
     """The anchor cell: M=K / staleness-0 / in-order uploads over the
     wire vs the sync twin's ``Federation.run()`` — ``final_param_dev``
     must stay <= 1e-5 (hard-gated)."""
-    from repro.api.federation import Federation, max_param_dev
     from repro.api.spec import spec_replace
-    from repro.net.client import ServiceClient
-    from repro.serve import sync_twin_spec
 
     anchor_spec = spec_replace(spec, {"schedule.buffer_size": 0,
                                       "schedule.max_staleness": 0,
                                       "schedule.rounds": int(sweeps)})
-    twin = Federation.from_spec(sync_twin_spec(anchor_spec))
-    twin.run()
     ctx = mp.get_context("spawn")
-    server, host, port = _boot_server(ctx, anchor_spec.to_dict())
-    client = ServiceClient(anchor_spec, host, port)
-    L = anchor_spec.data.num_clients
-    upload_lat: List[float] = []
-    accepted = 0
-    for _sweep in range(int(sweeps)):
-        for c in range(L):
-            bv, delta, w = client.client_update(c)
-            t0 = time.perf_counter()
-            receipt = client.submit(c, delta, w, base_version=bv)
-            upload_lat.append(time.perf_counter() - t0)
-            accepted += int(receipt["accepted"])
-    version, wire_params = client.fetch_model()
-    status = client.status()
-    client.shutdown(drain=False)
-    client.close()
+    spec_dict = anchor_spec.to_dict()
+    server, host, port = _boot_server(ctx, spec_dict, cpu=True)
+    proc, conn = _spawn(ctx, _anchor_client_main,
+                        (spec_dict, host, port, sweeps), cpu=True)
+    r = _collect(proc, conn, server, CLIENT_JOIN_TIMEOUT_S)
+    status = _wire_json(host, port, "GET", "/v1/status")
+    _wire_json(host, port, "POST", "/v1/shutdown?drain=false")
     server.join(60)
     cell: Dict[str, Any] = {
-        "final_param_dev": float(max_param_dev(twin.engine.params,
-                                               wire_params)),
-        "uploads": sweeps * L,
-        "accepted": accepted,
+        "final_param_dev": r["final_param_dev"],
+        "uploads": sweeps * anchor_spec.data.num_clients,
+        "accepted": r["accepted"],
         "aggregations": int(status["aggregations"]),
-        "version": int(version),
+        "version": int(r["version"]),
         "rejections": dict(status["rejections"])}
-    cell.update(_percentiles(upload_lat, "upload"))
+    cell.update(_percentiles(r["upload_lat"], "upload"))
     return cell
 
 

@@ -49,6 +49,7 @@ import time
 from repro.api import FederationSpec, scenario_names, scenario_spec
 from repro.api.spec import (STALENESS_POLICIES, DataSpec, ExecutionSpec,
                             ModelSpec, PartitionSpec, ScheduleSpec)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import FederationService, run_traffic
 
 
@@ -163,4 +164,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

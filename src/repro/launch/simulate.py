@@ -71,6 +71,7 @@ from repro.core.engine import RoundScheduler
 # canonical transform-registry home (the repro.core.engine re-export is
 # a deprecated shim since PR 5)
 from repro.core.transforms import TRANSFORMS
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _str_tuple(s: str):
@@ -337,4 +338,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -28,6 +28,7 @@ from repro.configs import ARCHS, get_config
 from repro.configs.base import NTM, FederatedConfig
 from repro.data.lm_data import SyntheticLMStream
 from repro.data.synthetic_lda import generate_lda_corpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_train_step
 from repro.models import transformer as tfm
@@ -132,4 +133,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

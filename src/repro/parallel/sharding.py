@@ -15,7 +15,6 @@ architecture.  Stacked (scanned) layer params get a leading None.
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -24,70 +23,6 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import AUDIO, HYBRID, MOE, NTM, SSM, ModelConfig
-
-
-# ---------------------------------------------------------------------------
-# jax version compatibility: ambient-mesh API
-# ---------------------------------------------------------------------------
-# ``jax.sharding.get_abstract_mesh`` / ``use_abstract_mesh`` are public from
-# jax 0.5.x; the pinned 0.4.37 build keeps the same machinery under
-# ``jax._src.mesh`` and only sets the *physical* mesh inside ``with mesh:``
-# blocks.  These wrappers present the new API on both builds:
-#   * get_abstract_mesh() -> AbstractMesh | None (None == no ambient mesh);
-#   * use_abstract_mesh(mesh) context manager accepting Mesh or AbstractMesh.
-def get_abstract_mesh():
-    """Ambient AbstractMesh, or None when no mesh is active."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        am = fn()
-        if am is not None and getattr(am, "axis_names", ()):
-            return am
-        return None
-    from jax._src import mesh as _mesh_lib
-    fn = getattr(_mesh_lib, "get_abstract_mesh", None)
-    if fn is not None:
-        am = fn()
-        if am is not None and getattr(am, "axis_names", ()):
-            return am
-    phys = _mesh_lib.thread_resources.env.physical_mesh
-    if phys is not None and not phys.empty:
-        return phys.abstract_mesh
-    return None
-
-
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` (jax >= 0.5) inside shard_map/pmap bodies;
-    the pinned build computes it as a counting psum (folded at trace time
-    for named axes, so this costs nothing)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def _as_abstract(mesh):
-    return getattr(mesh, "abstract_mesh", mesh)
-
-
-@contextlib.contextmanager
-def use_abstract_mesh(mesh):
-    """``jax.sharding.use_abstract_mesh`` on new jax; on the pinned build
-    fall back to ``jax._src.mesh.set_abstract_mesh`` and, when handed a
-    concrete Mesh, ALSO enter it as the physical mesh so bare-PartitionSpec
-    ``with_sharding_constraint`` keeps resolving."""
-    fn = getattr(jax.sharding, "use_abstract_mesh", None)
-    if fn is not None:
-        with fn(_as_abstract(mesh)):
-            yield
-        return
-    from jax._src import mesh as _mesh_lib
-    with contextlib.ExitStack() as stack:
-        set_am = getattr(_mesh_lib, "set_abstract_mesh", None)
-        if set_am is not None:
-            stack.enter_context(set_am(_as_abstract(mesh)))
-        if isinstance(mesh, Mesh):
-            stack.enter_context(mesh)
-        yield
 
 
 def fed_mesh(data: int) -> Mesh:
@@ -358,8 +293,8 @@ def _ambient():
     """(dp axes, model axis, sizes) from the ambient abstract mesh, or
     (None, None, {}) when no mesh is active (single-device tests).
     Respects the active sharding profile."""
-    am = get_abstract_mesh()
-    if am is None or not am.axis_names:
+    am = jax.sharding.get_abstract_mesh()
+    if not am.axis_names:
         return None, None, {}
     sizes = dict(am.shape)
     if _PROFILE == "fsdp":

@@ -1,13 +1,9 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — tests see ONE device;
 multi-device protocol tests spawn subprocesses that set the flag first.
 
-Historical note: the seed landed with 49 LM-arch tests broken against
-the pinned jax build (``jax.sharding.get_abstract_mesh`` and friends
-missing in jax 0.4.37), triaged here as a ``SEED_XFAILS`` block.  The
-compatibility shims in ``repro/parallel/sharding.py`` retired all 49;
-the block is gone and :func:`pytest_collection_modifyitems` below now
-guards the other direction — xfail debt can never silently
-re-accumulate.
+:func:`pytest_collection_modifyitems` below guards xfail debt: an
+xfail marker must cite an open item, so triaged breakage can never
+silently accumulate.
 """
 import re
 

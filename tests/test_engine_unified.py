@@ -286,6 +286,36 @@ def test_fused_ring_delivers_on_empty_cohort_round():
     assert loop.history[-1]["in_flight"] == 0      # buffer drained
 
 
+def test_fused_state_never_aliases_the_callers_trees():
+    """On accelerators the fused graphs donate params, server state and
+    transform state, deleting those buffers.  Before the first fused
+    round the engine copies them into buffers of its own, so a caller's
+    init tree — shared here with a loop twin, as the parity tests do —
+    is never what gets donated."""
+    cfg, loss, loss_sum, init, clients = _make_setup()
+    fed = FederatedConfig(num_clients=3, learning_rate=1e-2, max_rounds=2,
+                          rel_tol=0.0)
+    rc = RoundConfig(server_optimizer="fedavgm", server_lr=0.5)
+    vm = RoundEngine(loss, init, clients, fed, rc, batch_size=32,
+                     exec_mode="vmap", loss_sum_fn=loss_sum)
+    given = (vm.params, vm.server_state)
+    vm._build_vmap_fns()
+    vm._own_state()
+
+    def buffers(tree):
+        return {x.unsafe_buffer_pointer()
+                for x in jax.tree_util.tree_leaves(tree)}
+
+    assert not buffers(given) & buffers((vm.params, vm.server_state))
+    _leaves_equal(given[0], vm.params)
+    loop = RoundEngine(loss, init, clients, fed, rc, batch_size=32,
+                       exec_mode="loop")
+    for r in range(2):
+        vm.round(seed=r)
+        loop.round(seed=r)
+    assert _max_dev(loop.params, vm.params) < TOL
+
+
 # ---------------------------------------------------------------------------
 # 4. combine_arrivals validation (satellite fix)
 # ---------------------------------------------------------------------------

@@ -242,11 +242,10 @@ def test_fed_topk_ef_matches_ref(k, l, d, kk, rng):
     want_sent, want_err = ref.fed_topk_ef_ref(
         jnp.asarray(msgs), jnp.asarray(state)[ids], kk)
     sent, new_err = fed_topk_ef_pallas(jnp.asarray(msgs),
-                                       jnp.asarray(state),
-                                       jnp.asarray(ids), k_keep=kk,
+                                       jnp.asarray(state)[ids], k_keep=kk,
                                        interpret=True)
-    # the in-kernel gather + shared topk_keep_mask selection is BITWISE
-    # the oracle's math — identical coordinates, identical residuals
+    # the kernel's count-based selection is BITWISE the sort-based
+    # oracle's — identical coordinates, identical residuals
     np.testing.assert_array_equal(np.asarray(sent), np.asarray(want_sent))
     np.testing.assert_array_equal(np.asarray(new_err), np.asarray(want_err))
     assert np.all(np.count_nonzero(np.asarray(sent), axis=1) <= kk)
@@ -266,10 +265,74 @@ def test_fed_topk_ef_matches_loop_compression(rng):
     want = jax.vmap(
         lambda g, e: compress_with_error_feedback(g, e, frac))(
         msgs, state[ids])
-    sent, new_err = fed_topk_ef_pallas(msgs, state, ids, k_keep=kk,
+    sent, new_err = fed_topk_ef_pallas(msgs, state[ids], k_keep=kk,
                                        interpret=True)
     np.testing.assert_array_equal(np.asarray(sent), np.asarray(want[0]))
     np.testing.assert_array_equal(np.asarray(new_err), np.asarray(want[1]))
+
+
+def _topk_rule_input(case, rng, shape=(3, 300)):
+    x = rng.standard_normal(shape).astype(np.float32)
+    if case == "ties":                  # long runs tied at every rank
+        x = np.round(x * 4) / 4
+    elif case == "constant":
+        x = np.full(shape, -0.75, np.float32)
+    elif case == "bf16_neighbours":     # fp32 values one bf16 step apart
+        base = np.float32(1.0) + np.float32(2.0 ** -8) * rng.integers(
+            0, 3, shape)
+        x = (base + np.float32(2.0 ** -12) * rng.standard_normal(shape)
+             ).astype(np.float32)
+    elif case == "nonfinite":
+        x[:, ::17] = np.inf
+        x[:, 5::23] = np.nan
+    return jnp.asarray(x)
+
+
+TOPK_RULE_CASES = ["random", "ties", "constant", "bf16_neighbours",
+                   "nonfinite"]
+
+
+@pytest.mark.parametrize("case", TOPK_RULE_CASES)
+@pytest.mark.parametrize("k", [1, 75, 299, 300])
+def test_topk_keep_mask_matches_sort_oracle(case, k, rng):
+    """The count-based bisection the XLA paths and the kernel share keeps
+    exactly the sort-based oracle's coordinates: exactly k entries, the
+    k largest bf16 keys, ties toward the lower index."""
+    from repro.core.aggregation import topk_keep_mask
+    x = _topk_rule_input(case, rng)
+    got = np.asarray(topk_keep_mask(jnp.abs(x), k))
+    np.testing.assert_array_equal(got, np.asarray(ref.topk_mask_ref(x, k)))
+    assert np.all(got.sum(axis=-1) == k)
+
+
+@pytest.mark.parametrize("case", TOPK_RULE_CASES)
+def test_fed_topk_ef_kernel_selection_cases(case, rng):
+    """The kernel's in-VMEM selection on tie-heavy and non-finite rows
+    whose length is not a multiple of the 128-lane tile."""
+    from repro.kernels.fed_aggregate import fed_topk_ef_pallas
+    x = _topk_rule_input(case, rng, shape=(4, 333))
+    err = jnp.zeros_like(x)
+    want_sent, want_err = ref.fed_topk_ef_ref(x, err, 80)
+    sent, new_err = fed_topk_ef_pallas(x, err, k_keep=80, interpret=True)
+    np.testing.assert_array_equal(np.asarray(sent), np.asarray(want_sent))
+    np.testing.assert_array_equal(np.asarray(new_err), np.asarray(want_err))
+
+
+def test_bf16_magnitude_key_is_the_bf16_cast(rng):
+    """The integer key rounds exactly like an fp32 -> bf16 cast
+    (round-to-nearest-even), so ranking on it is ranking on the
+    bf16-quantized magnitude."""
+    from repro.core.aggregation import bf16_magnitude_key
+    x = np.concatenate([
+        rng.standard_normal(4096).astype(np.float32) * 10.0 ** rng.integers(
+            -30, 30, 4096).astype(np.float32),
+        np.float32([0.0, -0.0, np.inf, -np.inf, 1.0 + 2.0 ** -8,
+                    1.0 + 3 * 2.0 ** -8, 3.4e38])])
+    x = jnp.asarray(x)
+    cast = jax.lax.bitcast_convert_type(jnp.abs(x).astype(jnp.bfloat16),
+                                        jnp.uint16).astype(jnp.int32)
+    np.testing.assert_array_equal(np.asarray(bf16_magnitude_key(x)),
+                                  np.asarray(cast))
 
 
 DP_SECURE_CASES = [(5, 33), (8, 256), (3, 1), (9, 130)]

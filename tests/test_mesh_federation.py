@@ -48,7 +48,7 @@ def _spec(num_clients=8, mesh=None, **overrides):
                       val_docs_per_node=8),
         schedule=ScheduleSpec(rounds=3),
         execution=ExecutionSpec(
-            exec_mode="vmap", batch_size=16, learning_rate=1e-3,
+            exec_mode="vmap", batch_size=16, learning_rate=1e-4,
             mesh=MeshSpec.from_value(mesh) if mesh is not None else None))
     return spec_replace(base, overrides) if overrides else base
 
@@ -273,23 +273,25 @@ def test_mask_cancellation_bitwise_cross_device(host_mesh_devices,
 
 
 def test_sharding_compat_layer_under_fed_mesh(host_mesh_devices):
-    """The PR-6 compat shims compose with fed_mesh: axis_size resolves
-    the data axis inside a shard_map body and use_abstract_mesh scopes
-    the mesh for spec sanitization."""
-    from jax.experimental.shard_map import shard_map
+    """fed_mesh composes with the JAX mesh API the sharding rules call
+    directly: jax.lax.axis_size resolves the data axis inside a
+    jax.shard_map body, and jax.sharding.use_abstract_mesh scopes the
+    mesh that the in-model constraints read."""
     mesh = sharding.fed_mesh(4)
-    with sharding.use_abstract_mesh(mesh):
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        assert dict(jax.sharding.get_abstract_mesh().shape) == {"data": 4}
         # divisible dim keeps the axis, non-divisible drops it
         assert sharding.sanitize_spec(
             sharding.P("data"), (8, 3), mesh) == sharding.P("data")
         assert sharding.sanitize_spec(
             sharding.P("data"), (7, 3), mesh) == sharding.P()
+    assert not jax.sharding.get_abstract_mesh().axis_names
 
     def body(x):
-        return jnp.sum(x, keepdims=True) * sharding.axis_size("data")
+        return jnp.sum(x, keepdims=True) * jax.lax.axis_size("data")
 
-    out = shard_map(body, mesh=mesh, in_specs=sharding.P("data"),
-                    out_specs=sharding.P("data"))(
-                        jnp.ones((8,), jnp.float32))
+    out = jax.shard_map(body, mesh=mesh, in_specs=sharding.P("data"),
+                        out_specs=sharding.P("data"))(
+                            jnp.ones((8,), jnp.float32))
     assert out.shape == (4,)
     assert float(jnp.sum(out)) == 8.0 * 4

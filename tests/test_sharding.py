@@ -14,8 +14,8 @@ from repro.parallel.sharding import (batch_partition_spec,
                                      cache_partition_specs,
                                      param_partition_specs, sanitize_spec)
 
-SINGLE_POD = AbstractMesh((("data", 16), ("model", 16)))
-MULTI_POD = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+SINGLE_POD = AbstractMesh((16, 16), ("data", "model"))
+MULTI_POD = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _check_divisible(specs, shapes, mesh):
