@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Drive the federation's main path once on a TPU, at ProdLDA paper widths.
 
-    python chip_smoke.py             # one chip: train, pallas, service
+    python chip_smoke.py             # one chip: train, stale, pallas, service
     python chip_smoke.py --chips 4   # only the data=4 mesh vs one-chip twins
 
 The model is the paper's ProdLDA at its published widths (V=5000 terms,
@@ -14,6 +14,9 @@ seeded init.  Every phase goes through the entry points a user calls
   * train   — sync rounds on the fused vmap path (XLA backend), then an
               ``exec_mode="loop"`` twin from its own init: parameters
               within 1e-5, finite losses, one trace of the round graph;
+  * stale   — the ``straggler_ring`` scenario: late deltas ride the
+              in-graph ring buffer and are combined with staleness
+              discounts, against the loop path's host-side pending list;
   * pallas  — the ``pallas-topk`` and ``pallas-secure`` scenarios at
               these widths, each within 1e-5 of its XLA-backend twin;
   * service — the ``buffered_async_net`` preset behind ``repro.net`` on
@@ -163,10 +166,11 @@ def finite_losses(fed) -> bool:
     return bool(np.isfinite([h["loss"] for h in fed.history]).all())
 
 
-def run_pair(label, spec, twin_spec, corpus, clock):
+def run_pair(label, spec, twin_spec, corpus, clock, graph="fused_sync"):
     """The timed run and its reference twin, both timed the same way:
-    finite losses, one trace of each fused graph, no compile inside the
-    run's timed window, parameters within the parity bound."""
+    finite losses, one trace of the fused ``graph`` on each vmap side,
+    no compile inside the run's timed window, parameters within the
+    parity bound."""
     from repro.api import Federation, max_param_dev
     fed = Federation.from_spec(spec, corpus=corpus)
     stats = timed_run(fed, clock)
@@ -183,7 +187,7 @@ def run_pair(label, spec, twin_spec, corpus, clock):
           f"{label}: {stats['compiles_in_window']} compiles in the window")
     for f in (fed, twin):
         if f.engine.exec_mode == "vmap":
-            check(f.engine.trace_counts == {"fused_sync": 1},
+            check(f.engine.trace_counts == {graph: 1},
                   f"{label}: traces {f.engine.trace_counts}")
     check(dev <= PARITY_BOUND,
           f"{label}: max_param_dev {dev:.3e} > {PARITY_BOUND}")
@@ -205,6 +209,22 @@ def phase_train(seed, clock):
              spec_replace(spec, {"execution.exec_mode": "loop"}),
              corpus, clock)
     return spec, corpus
+
+
+def phase_stale(spec, corpus, clock):
+    from repro.api import scenario_spec, spec_replace
+    sspec = scenario_spec("straggler_ring", base=spec)
+    sch = sspec.schedule
+    fed = run_pair("stale vmap vs loop", sspec,
+                   spec_replace(sspec, {"execution.exec_mode": "loop"}),
+                   corpus, clock, graph="fused_stale")
+    log(f"[stale] straggler_prob={sch.straggler_prob} "
+        f"max_staleness={sch.max_staleness} "
+        f"staleness_decay={sch.staleness_decay}; per round "
+        f"(arrived, in_flight)="
+        f"{[(h['arrived'], h['in_flight']) for h in fed.history]}")
+    check(any(h["in_flight"] > 0 for h in fed.history[:-1]),
+          "stale: no delta was in flight before the last round")
 
 
 def phase_pallas(spec, corpus, clock):
@@ -320,6 +340,7 @@ def main(argv=None) -> int:
     else:
         base = run("train", phase_train, args.seed, clock)
         if base is not None:               # later phases reuse its data
+            run("stale", phase_stale, *base, clock)
             run("pallas", phase_pallas, *base, clock)
             run("service", phase_service, *base, clock)
     if failed:

@@ -65,7 +65,7 @@ from __future__ import annotations
 
 import warnings
 from dataclasses import dataclass
-from typing import Any, Dict, List, Mapping, Optional, Sequence
+from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -79,7 +79,8 @@ from repro.core import aggregation as agg
 from repro.core.transforms import StackedTransformCtx as _StackedCtx
 from repro.core.transforms import TransformCtx as _TransformCtx
 from repro.core.transforms import build_transforms as _build_transforms
-from repro.data.federated_split import stacked_round_batches
+from repro.data.federated_split import (round_minibatches, sample_minibatch,
+                                        stacked_round_batches)
 from repro.kernels import ops as kops
 from repro.optim.optimizers import global_norm
 from repro.parallel import sharding
@@ -127,6 +128,49 @@ def param_delta(old: Pytree, new: Pytree) -> Pytree:
     return jax.tree_util.tree_map(lambda a, b: b - a, old, new)
 
 
+def sgd_step_fn(loss_fn, learning_rate: float):
+    """One jitted local SGD step ``(params, batch) -> (loss, params')``.
+
+    The gradient and the update ``p - lr * g`` compile as one function,
+    as they do inside the stacked path's graph: run op by op, the update
+    rounds twice (``lr * g``, then the subtraction) where the compiled
+    graph fuses it into a single multiply-add, and those last-place
+    differences are enough to tip a top-k selection between the paths.
+    """
+    grad_fn = jax.value_and_grad(loss_fn)
+
+    def step(params, batch):
+        loss, grads = grad_fn(params, batch)
+        return loss, jax.tree_util.tree_map(
+            lambda p, g: p - learning_rate * g.astype(p.dtype),
+            params, grads)
+    return jax.jit(step)
+
+
+def client_round_update(step_fn, params: Pytree, client: ClientState,
+                        round_rng, *, local_epochs: int = 1,
+                        batch_size: int = 64) -> Tuple[Pytree, float, float]:
+    """Run E local SGD epochs on one client starting from the server
+    weights; return ``(delta, n_total, mean_loss)``.
+
+    With ``local_epochs=1`` the delta is exactly ``-lr * G_l`` for the
+    minibatch the Algorithm-1 trainer would draw from ``round_rng`` — the
+    identity that makes the engine reproduce Algorithm 1 (tested in
+    tests/test_rounds.py).  ``step_fn`` is :func:`sgd_step_fn` of the
+    client's local mean loss.
+    """
+    local = params
+    tot_loss, tot_n = 0.0, 0.0
+    for batch, n in round_minibatches(client.data, client.num_docs,
+                                      round_rng, batch_size=batch_size,
+                                      local_epochs=local_epochs):
+        loss, local = step_fn(local, batch)
+        tot_loss += float(loss) * n
+        tot_n += n
+    return param_delta(params, local), float(tot_n), \
+        tot_loss / max(tot_n, 1.0)
+
+
 def masked_mean_loss(loss_fn, loss_sum_fn=None):
     """Client objective for the stacked (vmap) execution path.
 
@@ -140,11 +184,15 @@ def masked_mean_loss(loss_fn, loss_sum_fn=None):
     client pads (every ``num_docs >= batch_size``); the engines enforce
     that precondition at construction.
 
-    The loop path runs this same objective through the same
-    :meth:`FederationEngine._build_client_update` function on the same
-    padded ``(E, P)`` stack, so in-batch noise (dropout /
-    reparametrization, drawn over the padded row count P) agrees between
-    the paths too.
+    CAVEAT (stochastic losses + padding): in-batch noise (dropout /
+    reparametrization) inside the loss is drawn over the PADDED row count
+    P, and threefry's counter layout is shape-dependent, so those draws
+    differ from the loop path's n-row draws even on the real rows.  A
+    padded client under a ``train=True`` loss therefore trains correctly
+    (same noise distribution, masked objective) but does NOT retrace the
+    loop trajectory bit-for-bit; the vmap==loop guarantee for stochastic
+    losses holds exactly when no client pads.  Deterministic losses
+    (``train=False``, the equivalence-test setting) are unaffected.
     """
     if loss_sum_fn is not None:
         def mean_loss(params, batch):
@@ -512,10 +560,8 @@ class FederationEngine:
                              "Algorithm-1 protocol; local_epochs must be 1 "
                              "(use message='delta' for multi-epoch clients)")
         self._mean_loss = masked_mean_loss(loss_fn, loss_sum_fn)
-        # the loop path pads a short client's batch to batch_size rows
-        # only when the objective masks padding (as the stacked path does)
-        self._pad_batches = loss_sum_fn is not None
-        self._client_fn = None         # built lazily (loop mode)
+        self._grad_fn = jax.jit(jax.value_and_grad(loss_fn))
+        self._step_fn = sgd_step_fn(loss_fn, self.fed.learning_rate)
         self._fused_sync = None
         self._fused_stale = None
         self._deliver_only = None
@@ -566,9 +612,6 @@ class FederationEngine:
             self._mesh = sharding.fed_mesh(mesh_data)
         self.pending: List[PendingUpdate] = []   # loop-mode reference
         self._ring = None                        # vmap-mode device buffer
-        # False until the fused graphs' donated state is engine-owned
-        # and placed (_own_state); a restored snapshot resets it
-        self._state_owned = False
 
         # -- server stage ----------------------------------------------
         self.server_opt = server or self._make_server_opt(self.rc)
@@ -679,36 +722,18 @@ class FederationEngine:
 
     # -- local update + transforms, one client (loop mode) ----------------
     def _local_message(self, l: int, round_key):
-        """One client's round message on the host loop.
-
-        The local update is the stacked path's own per-client function
-        (:meth:`_build_client_update`), jitted unbatched and fed this
-        client's ``(E, P)`` slice of the same minibatch draws
-        (:func:`stacked_round_batches` with one member): the two paths
-        then compute bitwise-equal local updates, so a selection
-        downstream (top-k) cannot tip on rounding differences between
-        them.  With ``local_epochs=1`` the delta is exactly ``-lr *
-        G_l`` for the minibatch Algorithm 1 would draw — the identity
-        that makes the engine reproduce Algorithm 1 (tests/test_rounds.py).
-        """
         c = self.clients[l]
         rng = jax.random.fold_in(round_key, l)
-        if self._client_fn is None:
-            self._client_fn = jax.jit(self._build_client_update())
-        e = self._e_max if self.message == "delta" else 1
-        n_epochs = int(self._epochs[l]) if self.message == "delta" else 1
-        p = self.batch_size if self._pad_batches \
-            else min(self.batch_size, c.num_docs)
-        stacked, counts = stacked_round_batches(
-            [c.data], [c.num_docs], round_key, [l], batch_size=p,
-            local_epochs=e)
-        msg, losses = self._client_fn(
-            self.params, {k: v[0] for k, v in stacked.items()},
-            np.int32(n_epochs))
-        counts = counts[0, :n_epochs]
-        n = float(counts.sum())
-        loss = float((np.asarray(losses)[:n_epochs] * counts).sum()
-                     / max(n, 1.0))
+        if self.message == "grad":
+            batch, n = sample_minibatch(c.data, c.num_docs, rng,
+                                        self.batch_size)
+            loss, msg = self._grad_fn(self.params, batch)
+            loss, n = float(loss), float(n)
+        else:
+            msg, n, loss = client_round_update(
+                self._step_fn, self.params, c, rng,
+                local_epochs=int(self._epochs[l]),
+                batch_size=self.batch_size)
         if self._transforms:
             ctx = _TransformCtx(round_key, rng, l, self._nmask, n, c)
             for _, fn in self._transforms:
@@ -994,7 +1019,6 @@ class FederationEngine:
         # the warning
         dn = jax.default_backend() != "cpu"
         if mesh is None:
-            self._rep_sharding = self._row_sharding = None
             self._fused_sync = jax.jit(
                 fused_sync, donate_argnums=(0, 1, 2) if dn else ())
             self._fused_stale = jax.jit(
@@ -1010,7 +1034,6 @@ class FederationEngine:
         # shardings of the next call).
         row = sharding.shardings_for(mesh, sharding.P("data"))
         rep = sharding.shardings_for(mesh, sharding.P())
-        self._rep_sharding, self._row_sharding = rep, row
         self._fused_sync = jax.jit(
             fused_sync, donate_argnums=(0, 1, 2) if dn else (),
             # (params, server_state, tstate, stacked, e_counts, weights,
@@ -1029,31 +1052,34 @@ class FederationEngine:
             in_shardings=(rep, rep, row, rep),
             out_shardings=(rep, rep, row, rep, rep, rep, rep))
 
+    def _place(self, tree, rows: bool = False):
+        """An engine-owned copy of ``tree``, placed as the fused graphs'
+        ``in_shardings`` expect: replicated, or row-sharded over
+        ``"data"`` for the client-axis state (transform state, ring)."""
+        if self._mesh is None:
+            return jax.tree_util.tree_map(
+                lambda x: jnp.array(x, copy=True), tree)
+        spec = sharding.P("data") if rows else sharding.P()
+        return jax.device_put(tree, sharding.shardings_for(self._mesh, spec),
+                              may_alias=False)
+
     def _own_state(self) -> None:
-        """Give the fused graphs state that this engine owns, placed as
-        their ``in_shardings`` expect.
+        """Give the fused graphs state that this engine owns.
 
         The graphs donate params, server state, transform state and the
         ring on accelerators, which deletes the donated buffers: the
         caller's ``init_params`` tree (or a restored snapshot) must
-        never be donated itself, so every array is copied once here.
-        On a mesh the copies go straight to the replicated / row
-        shardings — an array's sharding is part of its type, so round 1
-        then sees the same input types as every later round (one trace).
+        never be donated itself, so every array is copied once, when the
+        graphs are built and after a restore.  On a mesh the copies go
+        straight to the replicated / row shardings — an array's sharding
+        is part of its type, so round 1 then sees the same input types
+        as every later round (one trace).
         """
-        def own(tree, sharding):
-            if sharding is None:
-                return jax.tree_util.tree_map(
-                    lambda x: jnp.array(x, copy=True), tree)
-            return jax.device_put(tree, sharding, may_alias=False)
-
-        rep, row = self._rep_sharding, self._row_sharding
-        self.params = own(self.params, rep)
-        self.server_state = own(self.server_state, rep)
-        self._tstate = own(self._tstate, row)
+        self.params = self._place(self.params)
+        self.server_state = self._place(self.server_state)
+        self._tstate = self._place(self._tstate, rows=True)
         if self._ring is not None:
-            self._ring = own(self._ring, row)
-        self._state_owned = True
+            self._ring = self._place(self._ring, rows=True)
 
     def _init_ring(self):
         """Fixed-capacity device ring buffer for in-flight deltas.
@@ -1064,8 +1090,8 @@ class FederationEngine:
         most K*(max_staleness-1) older entries are still in flight.
         """
         c = max(1, self.scheduler.clients_per_round * self.rc.max_staleness)
-        return init_delta_buffer(self.params, c,
-                                 int_fields={"due": -1, "age": 0})
+        return self._place(init_delta_buffer(
+            self.params, c, int_fields={"due": -1, "age": 0}), rows=True)
 
     def _zero_cohort(self, k_fix: int):
         """All-padded stacked round template (cached): the fixed-K shape
@@ -1087,7 +1113,6 @@ class FederationEngine:
         cohort = [int(l) for l in cohort]
         if self._fused_sync is None:
             self._build_vmap_fns()
-        if not self._state_owned:
             self._own_state()
         ri = np.int32(r)
         # fixed-K stacking: availability churn shrinks the cohort, the
@@ -1143,9 +1168,6 @@ class FederationEngine:
             # straight into the in-graph ring buffer — no host round-trip
             if self._ring is None:
                 self._ring = self._init_ring()
-                if self._row_sharding is not None:
-                    self._ring = jax.device_put(self._ring,
-                                                self._row_sharding)
             delays = np.zeros((k_fix,), np.int32)
             delays[:len(cohort)] = [self._straggler_delay(r, l)
                                     for l in cohort]
@@ -1268,7 +1290,8 @@ class FederationEngine:
         for c, m in zip(self.clients, mems):
             c.error_memory = dev(m) if m is not None else None
         self.history = [dict(h) for h in state["history"]]
-        self._state_owned = False
+        if self._fused_sync is not None:
+            self._own_state()
 
     # -- one round --------------------------------------------------------
     def round(self, seed: Optional[int] = None) -> Dict[str, float]:

@@ -98,13 +98,8 @@ def _flat2(leaf):
 
 def _coef_matvec(c, leaf):
     """``sum_k c_k x_k`` of a stacked leaf as one matvec over its
-    flattened rows (no ring-sized temporary).  HIGHEST precision states
-    the fp32 contract: at the TPU's default precision XLA may run an
-    fp32 matmul as bf16 passes on the MXU (a 15-slot ring measured the
-    same at both precisions)."""
-    return jnp.matmul(c, _flat2(leaf).astype(jnp.float32),
-                      precision=jax.lax.Precision.HIGHEST
-                      ).reshape(leaf.shape[1:])
+    flattened rows (no ring-sized temporary)."""
+    return (c @ _flat2(leaf).astype(jnp.float32)).reshape(leaf.shape[1:])
 
 
 def _local_weighted_num(tree, w, backend: str, interpret: bool):

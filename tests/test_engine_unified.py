@@ -316,6 +316,38 @@ def test_fused_state_never_aliases_the_callers_trees():
     assert _max_dev(loop.params, vm.params) < TOL
 
 
+def test_restored_state_never_aliases_the_snapshot():
+    """A snapshot restored into an engine whose fused graphs already
+    exist is copied before the next (donating) round: the caller's
+    device arrays in the snapshot survive, and the run continues on the
+    same trajectory as an uninterrupted one."""
+    cfg, loss, loss_sum, init, clients = _make_setup()
+    fed = FederatedConfig(num_clients=3, learning_rate=1e-2, max_rounds=3,
+                          rel_tol=0.0)
+    rc = RoundConfig(server_optimizer="fedavgm", server_lr=0.5)
+
+    def engine():
+        return RoundEngine(loss, init, clients, fed, rc, batch_size=32,
+                           exec_mode="vmap", loss_sum_fn=loss_sum)
+
+    ref, vm = engine(), engine()
+    for r in range(3):
+        ref.round(seed=r)
+    vm.round(seed=0)
+    snap = vm.state_dict()
+    snap["params"] = jax.tree_util.tree_map(jnp.asarray, snap["params"])
+    vm.load_state_dict(snap)
+
+    def buffers(tree):
+        return {x.unsafe_buffer_pointer()
+                for x in jax.tree_util.tree_leaves(tree)}
+
+    assert not buffers(snap["params"]) & buffers(vm.params)
+    for r in (1, 2):
+        vm.round(seed=r)
+    _leaves_equal(ref.params, vm.params)
+
+
 # ---------------------------------------------------------------------------
 # 4. combine_arrivals validation (satellite fix)
 # ---------------------------------------------------------------------------

@@ -39,16 +39,18 @@ _max_dev = max_param_dev
 
 
 def _spec(num_clients=8, mesh=None, **overrides):
-    # lr sized so the tiny federation CONVERGES over the test horizon:
-    # a diverging model grows params without bound and turns the
-    # absolute 1e-5 parity bound into noise measurement
+    # lr and corpus seed chosen so the tiny federation CONVERGES over
+    # the test horizon: a diverging model grows params without bound and
+    # turns the absolute 1e-5 parity bound into noise measurement (at
+    # lr 1e-3 the corpora of data seeds 0, 3 and 5 blow up to inf by
+    # round 3; seed 1 trains down smoothly at L=8 and L=16)
     base = FederationSpec(
         model=ModelSpec(vocab=128, topics=4, hidden=16),
         data=DataSpec(num_clients=num_clients, docs_per_node=40,
-                      val_docs_per_node=8),
+                      val_docs_per_node=8, seed=1),
         schedule=ScheduleSpec(rounds=3),
         execution=ExecutionSpec(
-            exec_mode="vmap", batch_size=16, learning_rate=1e-4,
+            exec_mode="vmap", batch_size=16, learning_rate=1e-3,
             mesh=MeshSpec.from_value(mesh) if mesh is not None else None))
     return spec_replace(base, overrides) if overrides else base
 
