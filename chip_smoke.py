@@ -28,9 +28,10 @@ seeded init.  Every phase goes through the entry points a user calls
               its unsharded one-chip twin, one trace per graph.
 
 Everything runs in this one process: a chip belongs to one process at a
-time.  Compile seconds come from JAX's own compile events; round and
-request times are host wall-clock around work that ends in
-``block_until_ready``.  The last line of stdout is one JSON object,
+time.  Compile seconds and counts come from JAX's own compile events;
+the speed of a round is measured by the chip benchmark
+(``benchmarks/chip/run.py``), not here.  The last line of stdout is one
+JSON object,
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
 Without a TPU, or when any phase fails, the script exits non-zero and
 prints no such line.
@@ -39,7 +40,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import statistics
 import sys
 import time
 import traceback
@@ -55,8 +55,7 @@ PAPER_DOCS_PER_NODE = 10_000
 DOCS_PER_NODE = 1_000          # cut from the paper's 10,000: host time
 BATCH = 64
 LR = 2e-3
-WARMUP_ROUNDS = 2
-TIMED_ROUNDS = 3
+ROUNDS = 5                     # the first compiles; no later one may
 PARITY_BOUND = 1e-5
 INFER_BATCHES = 3
 
@@ -127,39 +126,26 @@ def paper_spec(num_clients: int, seed: int):
         model=ModelSpec(vocab=VOCAB, topics=TOPICS, hidden=HIDDEN),
         data=DataSpec(num_clients=num_clients, docs_per_node=DOCS_PER_NODE,
                       val_docs_per_node=BATCH, seed=seed),
-        schedule=ScheduleSpec(rounds=WARMUP_ROUNDS + TIMED_ROUNDS),
+        schedule=ScheduleSpec(rounds=ROUNDS),
         execution=ExecutionSpec(exec_mode="vmap", batch_size=BATCH,
                                 learning_rate=LR, seed=seed))
 
 
-def timed_run(fed, clock: CompileClock) -> dict:
-    """Warm-up rounds, then timed rounds that each end in
-    ``block_until_ready``; counts the compiles inside the timed window."""
+def run_rounds(fed, clock: CompileClock) -> dict:
+    """``ROUNDS`` rounds, each ending in ``block_until_ready``; the
+    compile seconds of the first, and the compiles of the rest."""
     import jax
 
     def one_round():
-        t0 = time.perf_counter()
         fed.step()
         jax.block_until_ready(fed.params)
-        return time.perf_counter() - t0
 
     clock.lap()
-    first = one_round()
+    one_round()
     compile_s, _ = clock.lap()
-    for _ in range(WARMUP_ROUNDS - 1):
+    for _ in range(ROUNDS - 1):
         one_round()
-    rounds = [one_round() for _ in range(TIMED_ROUNDS)]
-    return {"compile_s": compile_s, "first_round_s": first,
-            "round_ms": [1e3 * t for t in rounds],
-            "median_round_ms": 1e3 * statistics.median(rounds),
-            "compiles_in_window": clock.lap()[1]}
-
-
-def describe(stats: dict) -> str:
-    return (f"compile_s={stats['compile_s']:.3f} "
-            f"first_round_s={stats['first_round_s']:.3f} "
-            f"round_ms={[round(t, 3) for t in stats['round_ms']]} "
-            f"median_round_ms={stats['median_round_ms']:.3f}")
+    return {"compile_s": compile_s, "compiles_in_window": clock.lap()[1]}
 
 
 def finite_losses(fed) -> bool:
@@ -167,19 +153,20 @@ def finite_losses(fed) -> bool:
 
 
 def run_pair(label, spec, twin_spec, corpus, clock, graph="fused_sync"):
-    """The timed run and its reference twin, both timed the same way:
-    finite losses, one trace of the fused ``graph`` on each vmap side,
-    no compile inside the run's timed window, parameters within the
-    parity bound."""
+    """The run and its reference twin, driven the same way: finite
+    losses, one trace of the fused ``graph`` on each vmap side, no
+    compile after the run's first round, parameters within the parity
+    bound."""
     from repro.api import Federation, max_param_dev
     fed = Federation.from_spec(spec, corpus=corpus)
-    stats = timed_run(fed, clock)
+    stats = run_rounds(fed, clock)
     twin = Federation.from_spec(twin_spec, corpus=corpus)
-    twin_stats = timed_run(twin, clock)
+    twin_stats = run_rounds(twin, clock)
     dev = max_param_dev(fed.params, twin.params)
-    log(f"[{label}] run: {describe(stats)} traces={fed.engine.trace_counts}")
+    log(f"[{label}] run: compile_s={stats['compile_s']:.3f} "
+        f"traces={fed.engine.trace_counts}")
     log(f"[{label}] twin ({twin.engine.exec_mode}, mesh "
-        f"{twin.mesh_shape}): {describe(twin_stats)}")
+        f"{twin.mesh_shape}): compile_s={twin_stats['compile_s']:.3f}")
     log(f"[{label}] max_param_dev={dev:.3e} (bound {PARITY_BOUND})")
     check(finite_losses(fed) and finite_losses(twin),
           f"{label}: non-finite loss")
@@ -237,7 +224,6 @@ def phase_pallas(spec, corpus, clock):
 
 
 def phase_service(spec, corpus, clock):
-    import jax
     from repro.api import scenario_spec
     from repro.net import BackgroundServer, ServiceClient
     from repro.serve import REJECT_REASONS, FederationService
@@ -248,23 +234,16 @@ def phase_service(spec, corpus, clock):
     with BackgroundServer(service) as bg:
         client = ServiceClient(sspec, bg.host, bg.port, corpus=corpus)
         try:
-            receipts, update_ms, upload_ms, infer_ms = [], [], [], []
+            receipts = []
             for c in range(n):
-                t0 = time.perf_counter()
                 bv, delta, w = client.client_update(c)
-                jax.block_until_ready(delta)
-                update_ms.append(1e3 * (time.perf_counter() - t0))
-                t0 = time.perf_counter()
                 receipts.append(client.submit(c, delta, w, base_version=bv))
-                upload_ms.append(1e3 * (time.perf_counter() - t0))
             status = client.status()
             rng = np.random.default_rng(0)
             for i in range(INFER_BATCHES):
                 bow = corpus.node_bows[i % n][rng.choice(
                     len(corpus.node_bows[i % n]), BATCH, replace=False)]
-                t0 = time.perf_counter()
                 theta = client.infer(bow)
-                infer_ms.append(1e3 * (time.perf_counter() - t0))
                 check(theta.shape == (BATCH, TOPICS),
                       f"infer: theta shape {theta.shape}")
                 check(bool(np.isfinite(theta).all()), "infer: non-finite")
@@ -278,10 +257,8 @@ def phase_service(spec, corpus, clock):
         f"accepted={sum(r['accepted'] for r in receipts)} "
         f"aggregations={status['aggregations']} "
         f"version={status['version']} rejections={status['rejections']} "
-        f"compile_s={compile_s:.3f} "
-        f"client_update_ms={[round(t, 3) for t in update_ms]} "
-        f"upload_ms={[round(t, 3) for t in upload_ms]} "
-        f"infer_ms={[round(t, 3) for t in infer_ms]} (batch {BATCH})")
+        f"compile_s={compile_s:.3f} infer batches={INFER_BATCHES} "
+        f"(batch {BATCH})")
     check(status["aggregations"] >= 2,
           f"service: {status['aggregations']} aggregations")
     check(status["version"] >= 2, f"service: version {status['version']}")

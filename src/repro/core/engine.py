@@ -71,6 +71,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.configs.base import FederatedConfig, RoundConfig
 from repro.core import aggregation as agg
 # the transform registry's canonical home is core/transforms.py (PR 4);
@@ -846,7 +847,8 @@ class FederationEngine:
                     mesh=mesh)
                 tstate = dict(tstate)
                 for name, t in transforms:
-                    msgs, st = t.stacked(msgs, ctx, tstate.get(name))
+                    with spans.scope(f"transform/{name}"):
+                        msgs, st = t.stacked(msgs, ctx, tstate.get(name))
                     if name in tstate:
                         tstate[name] = st
             valid = w > 0.0
@@ -858,8 +860,9 @@ class FederationEngine:
 
         def stacked_messages(params, stacked, e_counts):
             """All K clients' local updates in one graph -> (K, ...)."""
-            return jax.vmap(client_update, in_axes=(None, 0, 0))(
-                params, stacked, e_counts)
+            with spans.scope(spans.LOCAL_UPDATE):
+                return jax.vmap(client_update, in_axes=(None, 0, 0))(
+                    params, stacked, e_counts)
 
         def fused_sync(params, server_state, tstate, stacked, e_counts,
                        weights, ids, round_key, round_idx):
@@ -873,15 +876,17 @@ class FederationEngine:
             msgs = pin_rows(msgs)
             w = weights.astype(jnp.float32)
             msgs, tstate = transform_stage(msgs, tstate, round_key, ids, w)
-            bar = kops.fed_weighted_combine(msgs, w, backend=kb, mesh=mesh)
-            upd_p, upd_s = server_opt.apply(params, bar, server_state,
-                                            round_idx)
-            has = w.sum() > 0.0
-            sel = lambda o, n_: tmap(  # noqa: E731
-                lambda a, b: jnp.where(has, b, a), o, n_)
-            new_params, new_state = sel(params, upd_p), sel(server_state,
-                                                            upd_s)
-            rel = jnp.where(has, _rel_change(params, new_params), 0.0)
+            with spans.scope(spans.AGGREGATE):
+                bar = kops.fed_weighted_combine(msgs, w, backend=kb,
+                                                mesh=mesh)
+                upd_p, upd_s = server_opt.apply(params, bar, server_state,
+                                                round_idx)
+                has = w.sum() > 0.0
+                sel = lambda o, n_: tmap(  # noqa: E731
+                    lambda a, b: jnp.where(has, b, a), o, n_)
+                new_params, new_state = sel(params, upd_p), sel(server_state,
+                                                                upd_s)
+                rel = jnp.where(has, _rel_change(params, new_params), 0.0)
             return new_params, new_state, tstate, losses, rel
 
         def ring_deliver(params, server_state, ring, round_idx,
@@ -931,25 +936,26 @@ class FederationEngine:
             denom = jnp.maximum(total_w, 1e-12)
             ring_coef = due_w * discount                         # (C,)
 
-            # the ring and fresh numerators through the selected
-            # backend (on a mesh: per-device partials + one psum each,
-            # kernels/ops.py), then the replicated division
-            acc = kops.fed_weighted_sum(ring["delta"], ring_coef,
-                                        backend=kb, mesh=mesh)
-            if fresh is not None:
-                acc = tmap(lambda a, b: a + b, acc,
-                           kops.fed_weighted_sum(fresh[0], fresh_w,
-                                                 backend=kb, mesh=mesh))
-            bar = tmap(lambda a: a / denom, acc)
-            upd_p, upd_s = server_opt.apply(params, bar, server_state,
-                                            round_idx)
-            # an all-straggler round leaves params AND server state alone
-            # (momentum must not decay on a no-arrival round)
-            sel = lambda o, n_: tmap(  # noqa: E731
-                lambda a, b: jnp.where(has, b, a), o, n_)
-            new_params, new_state = sel(params, upd_p), sel(server_state,
-                                                            upd_s)
-            rel = jnp.where(has, _rel_change(params, new_params), 0.0)
+            with spans.scope(spans.AGGREGATE):
+                # the ring and fresh numerators through the selected
+                # backend (on a mesh: per-device partials + one psum
+                # each, kernels/ops.py), then the replicated division
+                acc = kops.fed_weighted_sum(ring["delta"], ring_coef,
+                                            backend=kb, mesh=mesh)
+                if fresh is not None:
+                    acc = tmap(lambda a, b: a + b, acc,
+                               kops.fed_weighted_sum(fresh[0], fresh_w,
+                                                     backend=kb, mesh=mesh))
+                bar = tmap(lambda a: a / denom, acc)
+                upd_p, upd_s = server_opt.apply(params, bar, server_state,
+                                                round_idx)
+                # an all-straggler round leaves params AND server state
+                # alone (momentum must not decay on a no-arrival round)
+                sel = lambda o, n_: tmap(  # noqa: E731
+                    lambda a, b: jnp.where(has, b, a), o, n_)
+                new_params, new_state = sel(params, upd_p), sel(server_state,
+                                                                upd_s)
+                rel = jnp.where(has, _rel_change(params, new_params), 0.0)
             # delivered AND superseded slots both leave the ring — a
             # superseded delta will never deliver
             gone = due | sup
@@ -1154,15 +1160,14 @@ class FederationEngine:
                            < e_counts[:, None])
         weights = counts.sum(axis=1)        # (K,) Eq. (2) weights, pad=0
 
-        superseded = 0
         if not self._stale_enabled:
             # fast path: one jitted call per round, donated buffers
-            (self.params, self.server_state, self._tstate, losses,
-             rel) = self._fused_sync(
-                self.params, self.server_state, self._tstate, stacked,
-                e_counts, weights, ids, round_key, ri)
-            arrived, in_flight = len(cohort), 0
-            rel = float(rel)
+            with spans.span(spans.DISPATCH):
+                (self.params, self.server_state, self._tstate, losses,
+                 rel) = self._fused_sync(
+                    self.params, self.server_state, self._tstate, stacked,
+                    e_counts, weights, ids, round_key, ri)
+            arrived, in_flight, n_sup = len(cohort), 0, 0
         else:
             # straggler regime, equally fused: the stacked deltas go
             # straight into the in-graph ring buffer — no host round-trip
@@ -1171,30 +1176,33 @@ class FederationEngine:
             delays = np.zeros((k_fix,), np.int32)
             delays[:len(cohort)] = [self._straggler_delay(r, l)
                                     for l in cohort]
-            (self.params, self.server_state, self._tstate, self._ring,
-             losses, rel, arrived, in_flight, n_sup) = self._fused_stale(
-                self.params, self.server_state, self._tstate, self._ring,
-                stacked, e_counts, weights, delays, ids, round_key, ri)
-            rel = float(rel)
-            arrived, in_flight = int(arrived), int(in_flight)
-            superseded = int(n_sup)
+            with spans.span(spans.DISPATCH):
+                (self.params, self.server_state, self._tstate, self._ring,
+                 losses, rel, arrived, in_flight, n_sup) = self._fused_stale(
+                    self.params, self.server_state, self._tstate,
+                    self._ring, stacked, e_counts, weights, delays, ids,
+                    round_key, ri)
 
-        losses = np.asarray(losses)             # (K, E) per-epoch means
-        # zero-count epochs (padded rows under homogeneous E, where the
-        # in-scan loss gate is compiled out; gated-off hetero epochs) may
-        # carry garbage values — 0-weighting alone would keep a NaN/inf
-        # (0 * inf = nan), so mask them out before the weighted average
-        losses = np.where(counts > 0, losses, 0.0)
-        client_loss = (losses * counts).sum(axis=1) \
-            / np.maximum(counts.sum(axis=1), 1.0)
-        return {"round": r,
-                "loss": float(np.average(client_loss, weights=weights))
-                if cohort else float("nan"),
-                "rel_change": rel,
-                "participants": len(cohort),
-                "arrived": arrived,
-                "superseded": superseded,
-                "in_flight": in_flight}
+        with spans.span(spans.FETCH):
+            rel, arrived = float(rel), int(arrived)
+            in_flight, superseded = int(in_flight), int(n_sup)
+            losses = np.asarray(losses)         # (K, E) per-epoch means
+            # zero-count epochs (padded rows under homogeneous E, where
+            # the in-scan loss gate is compiled out; gated-off hetero
+            # epochs) may carry garbage values — 0-weighting alone would
+            # keep a NaN/inf (0 * inf = nan), so mask them out before the
+            # weighted average
+            losses = np.where(counts > 0, losses, 0.0)
+            client_loss = (losses * counts).sum(axis=1) \
+                / np.maximum(counts.sum(axis=1), 1.0)
+            return {"round": r,
+                    "loss": float(np.average(client_loss, weights=weights))
+                    if cohort else float("nan"),
+                    "rel_change": rel,
+                    "participants": len(cohort),
+                    "arrived": arrived,
+                    "superseded": superseded,
+                    "in_flight": in_flight}
 
     # -- stopping ---------------------------------------------------------
     @staticmethod

@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
+
 
 # ---------------------------------------------------------------------------
 # partitioner registry
@@ -340,6 +342,22 @@ def stacked_round_batches(
             "(fixed-K padding) or resize the cohort/mesh")
     e = local_epochs
     p = batch_size
+    # group cohort members by draw shape so each group is one jitted call
+    groups: Dict[Tuple[int, int], List[int]] = {}
+    for i, nd in enumerate(num_docs):
+        groups.setdefault((int(nd), min(batch_size, int(nd))), []).append(i)
+
+    draws = []
+    with spans.span(spans.DRAW):
+        for (nd, n), members in groups.items():
+            fn = _stacked_draw_fn(nd, n, e)
+            ids = jnp.asarray([int(client_ids[i]) for i in members],
+                              jnp.uint32)
+            idx_g, rng_g = fn(round_key, ids)
+            draws.append((n, members, np.asarray(idx_g),     # (G, E, n)
+                          np.asarray(rng_g, np.uint32)))     # (G, E, 2)
+
+    # zero pages: the fill below touches them, inside its span
     stacked: Dict[str, np.ndarray] = {
         key: np.zeros((k_stack, e, p) + v.shape[1:],
                       np.asarray(v).dtype)
@@ -348,23 +366,14 @@ def stacked_round_batches(
     stacked["doc_mask"] = np.zeros((k_stack, e, p), np.float32)
     stacked["rng"] = np.zeros((k_stack, e, 2), np.uint32)
     counts = np.zeros((k_stack, e), np.float32)
-
-    # group cohort members by draw shape so each group is one jitted call
-    groups: Dict[Tuple[int, int], List[int]] = {}
-    for i, nd in enumerate(num_docs):
-        groups.setdefault((int(nd), min(batch_size, int(nd))), []).append(i)
-
-    for (nd, n), members in groups.items():
-        fn = _stacked_draw_fn(nd, n, e)
-        ids = jnp.asarray([int(client_ids[i]) for i in members], jnp.uint32)
-        idx_g, rng_g = fn(round_key, ids)
-        idx_g = np.asarray(idx_g)                    # (G, E, n)
-        rng_g = np.asarray(rng_g, np.uint32)         # (G, E, 2)
-        for g, i in enumerate(members):
-            for key, v in datas[i].items():
-                # one (E, n)-index gather per (client, key)
-                stacked[key][i, :, :n] = np.asarray(v)[idx_g[g]]
-            stacked["doc_mask"][i, :, :n] = 1.0
-            stacked["rng"][i] = rng_g[g]
-            counts[i, :] = n
+    with spans.span(spans.GATHER,
+                    bytes=sum(a.nbytes for a in stacked.values())):
+        for n, members, idx_g, rng_g in draws:
+            for g, i in enumerate(members):
+                for key, v in datas[i].items():
+                    # one (E, n)-index gather per (client, key)
+                    stacked[key][i, :, :n] = np.asarray(v)[idx_g[g]]
+                stacked["doc_mask"][i, :, :n] = 1.0
+                stacked["rng"][i] = rng_g[g]
+                counts[i, :] = n
     return stacked, counts
