@@ -80,7 +80,8 @@ from repro.core import aggregation as agg
 from repro.core.transforms import StackedTransformCtx as _StackedCtx
 from repro.core.transforms import TransformCtx as _TransformCtx
 from repro.core.transforms import build_transforms as _build_transforms
-from repro.data.federated_split import (round_minibatches, sample_minibatch,
+from repro.data.federated_split import (place_corpus, round_minibatches,
+                                        row_nbytes, sample_minibatch,
                                         stacked_round_batches)
 from repro.kernels import ops as kops
 from repro.optim.optimizers import global_norm
@@ -567,6 +568,8 @@ class FederationEngine:
         self._fused_stale = None
         self._deliver_only = None
         self._zero_stacked = None      # all-padded round template (vmap)
+        self._corpus = None            # the clients' rows on the device
+        self._corpus_open = True       # decided at the first dispatch
         # one entry per TRACE of each fused graph (the bodies bump it at
         # trace time only) — the retrace-free fixed-K contract is
         # asserted against this in tests and the CI bench payload
@@ -1087,6 +1090,39 @@ class FederationEngine:
         if self._ring is not None:
             self._ring = self._place(self._ring, rows=True)
 
+    def _resident_corpus(self, fused, args, stacked):
+        """Every client's rows placed on the device of the engine's state
+        (``place_corpus``), or None: each cohort is then filled in host
+        numpy and copied.
+
+        Decided at the first round's dispatch of the round program
+        ``fused(*args)`` on the cohort ``stacked``, from what the device
+        reports: ``bytes_limit`` less ``bytes_in_use`` (the placed state
+        and straggler ring, and whatever else the process holds) must
+        hold the rows, the cohort and the larger of the round program's
+        temporaries (its compiled ``memory_analysis``; the call reuses
+        that compile) and the gather's (one cohort of rows as wide as
+        the resident ones).  A device that reports no limit (the CPU)
+        takes them.  A mesh keeps the host fill: its clients' rows would
+        have to be sharded by client.
+        """
+        if self._mesh is not None:
+            return None
+        datas = [c.data for c in self.clients]
+        (device,) = jax.tree_util.tree_leaves(self.params)[0].devices()
+        stats = device.memory_stats() or {}
+        if "bytes_limit" in stats:
+            row, docs = row_nbytes(datas[0]), sum(
+                len(next(iter(d.values()))) for d in datas)
+            temp = getattr(fused.lower(*args).compile().memory_analysis(),
+                           "temp_size_in_bytes", 0)
+            need = row * (docs + 1) + sum(
+                np.asarray(v).nbytes for v in stacked.values()) + max(
+                temp, row * np.size(stacked["doc_mask"]))
+            if need > stats["bytes_limit"] - stats.get("bytes_in_use", 0):
+                return None
+        return place_corpus(datas, device)
+
     def _init_ring(self):
         """Fixed-capacity device ring buffer for in-flight deltas.
 
@@ -1146,7 +1182,8 @@ class FederationEngine:
                 cohort, batch_size=self.batch_size,
                 local_epochs=self._e_max, pad_to=k_fix,
                 shard_multiple=self._mesh.shape["data"]
-                if self._mesh is not None else None)
+                if self._mesh is not None else None,
+                resident=self._corpus)
         else:
             stacked, counts = self._zero_cohort(k_fix)
         e_counts = np.zeros((k_fix,), np.int32)
@@ -1162,12 +1199,9 @@ class FederationEngine:
 
         if not self._stale_enabled:
             # fast path: one jitted call per round, donated buffers
-            with spans.span(spans.DISPATCH):
-                (self.params, self.server_state, self._tstate, losses,
-                 rel) = self._fused_sync(
-                    self.params, self.server_state, self._tstate, stacked,
+            fused = self._fused_sync
+            args = (self.params, self.server_state, self._tstate, stacked,
                     e_counts, weights, ids, round_key, ri)
-            arrived, in_flight, n_sup = len(cohort), 0, 0
         else:
             # straggler regime, equally fused: the stacked deltas go
             # straight into the in-graph ring buffer — no host round-trip
@@ -1176,12 +1210,21 @@ class FederationEngine:
             delays = np.zeros((k_fix,), np.int32)
             delays[:len(cohort)] = [self._straggler_delay(r, l)
                                     for l in cohort]
-            with spans.span(spans.DISPATCH):
-                (self.params, self.server_state, self._tstate, self._ring,
-                 losses, rel, arrived, in_flight, n_sup) = self._fused_stale(
-                    self.params, self.server_state, self._tstate,
+            fused = self._fused_stale
+            args = (self.params, self.server_state, self._tstate,
                     self._ring, stacked, e_counts, weights, delays, ids,
                     round_key, ri)
+        if self._corpus_open:
+            self._corpus_open = False
+            self._corpus = self._resident_corpus(fused, args, stacked)
+        with spans.span(spans.DISPATCH):
+            out = fused(*args)
+        if not self._stale_enabled:
+            self.params, self.server_state, self._tstate, losses, rel = out
+            arrived, in_flight, n_sup = len(cohort), 0, 0
+        else:
+            (self.params, self.server_state, self._tstate, self._ring,
+             losses, rel, arrived, in_flight, n_sup) = out
 
         with spans.span(spans.FETCH):
             rel, arrived = float(rel), int(arrived)

@@ -28,7 +28,11 @@ with the FedAvg key schedule — epoch 0 reuses the round key, so
 """
 from __future__ import annotations
 
+import contextlib
+import functools
+import math
 import re
+from dataclasses import dataclass
 from typing import (Any, Callable, Dict, Iterator, List, Optional, Sequence,
                     Tuple)
 
@@ -281,6 +285,83 @@ def _stacked_draw_fn(num_docs: int, n: int, local_epochs: int):
     return fn
 
 
+# the TPU lays out a 2-D array whose rows are not a multiple of this many
+# elements wide column-major (f32[100001, 5000] is), and a row gather would
+# then copy the whole corpus into rows first, every round
+LANES = 128
+
+
+@dataclass(frozen=True)
+class ResidentCorpus:
+    """Every client's rows, placed on the device once, so that a round's
+    cohort is gathered there (:func:`stacked_round_batches`).
+
+    ``rows[key]`` holds all clients' documents of ``key`` in client
+    order, each flattened and zero-padded to a multiple of
+    :data:`LANES` elements, in the dtype the device reads from the host
+    arrays, then one all-zero row that padding points at;
+    ``shapes`` gives each key's document shape, ``offsets[l]`` is
+    client ``l``'s first row, and ``device`` holds the rows.
+    """
+    rows: Dict[str, jax.Array]
+    shapes: Tuple[Tuple[str, Tuple[int, ...]], ...]
+    offsets: np.ndarray
+    device: Any
+
+
+def _lanes(width: int) -> int:
+    return -(-width // LANES) * LANES
+
+
+def row_nbytes(data: Dict[str, Any]) -> int:
+    """Device bytes of one document of ``data`` in a resident corpus."""
+    return sum(_lanes(math.prod(np.shape(v)[1:]))
+               * jax.dtypes.canonicalize_dtype(v.dtype).itemsize
+               for v in data.values())
+
+
+def place_corpus(datas: Sequence[Dict[str, np.ndarray]],
+                 device) -> ResidentCorpus:
+    """Place every client's ``data`` (global client order) on ``device``:
+    one flat array per key, plus the zero row.  The rows stay
+    uncommitted, as an engine's own state is, so the round program sees
+    the same argument types whether its cohort was gathered from them or
+    copied from the host."""
+    sizes = [len(next(iter(d.values()))) for d in datas]
+    offsets = np.concatenate([[0], np.cumsum(sizes)[:-1]]).astype(np.int32)
+    rows, shapes = {}, []
+    for key, v0 in datas[0].items():
+        shape = np.shape(v0)[1:]
+        w = math.prod(shape)
+        flat = np.zeros((sum(sizes) + 1, _lanes(w)), np.asarray(v0).dtype)
+        for d, at, n in zip(datas, offsets, sizes):
+            flat[at:at + n, :w] = np.asarray(d[key]).reshape(n, w)
+        with jax.default_device(device):
+            rows[key] = jax.device_put(flat)
+        shapes.append((key, shape))
+    return ResidentCorpus(rows, tuple(shapes), offsets, device)
+
+
+@jax.jit
+def _scatter_draws(index, rng, idx, rng_g, at, off):
+    """Write one draw group into a round's ``(K, E, P)`` global row index
+    and ``(K, E, 2)`` model keys: cohort row ``at[g]`` reads rows
+    ``off[g] + idx[g]``, its client's first row plus its draws (``idx``
+    is ``(G, E, n)``).  Compiled per group shape ``(G, n)``, as the
+    draws are."""
+    return (index.at[at, :, :idx.shape[-1]].set(off[:, None, None] + idx),
+            rng.at[at].set(rng_g))
+
+
+@functools.partial(jax.jit, static_argnames=("shapes",))
+def _resident_gather(rows, index, *, shapes):
+    """Every key's ``(K, E, P, ...)`` cohort arrays, read from the
+    resident rows through the global row ``index``.  Compiled once per
+    corpus and ``(K, E, P)``, whatever the cohort."""
+    return {key: rows[key][index][..., :math.prod(shape)].reshape(
+        index.shape + shape) for key, shape in shapes}
+
+
 def stacked_round_batches(
     datas: Sequence[Dict[str, np.ndarray]],
     num_docs: Sequence[int],
@@ -291,7 +372,8 @@ def stacked_round_batches(
     local_epochs: int = 1,
     pad_to: Optional[int] = None,
     shard_multiple: Optional[int] = None,
-) -> Tuple[Dict[str, np.ndarray], np.ndarray]:
+    resident: Optional[ResidentCorpus] = None,
+) -> Tuple[Dict[str, Any], np.ndarray]:
     """Assemble one round's cohort minibatches into a leading client axis.
 
     For each cohort member ``i`` (global client id ``client_ids[i]``,
@@ -319,9 +401,15 @@ def stacked_round_batches(
     contract of DESIGN.md §4.  The real rows are byte-identical to the
     unpadded call, so padding never perturbs a draw.
 
-    The gathering itself is host-side numpy; the single resulting
-    transfer replaces the per-client-per-epoch device round-trips of the
-    loop path.
+    With ``resident`` (the engine's :func:`place_corpus`, every client's
+    rows already on the device, indexed by global client id) the drawn
+    indices never leave the device: each draw group writes its clients'
+    rows into one ``(K, E, P)`` global row index (:func:`_scatter_draws`)
+    and one program reads every data key through it
+    (:func:`_resident_gather`), byte-identical to the host fill; only
+    ``doc_mask`` and ``counts`` are host numpy.
+    Without it the rows are filled in host numpy from ``datas`` and
+    copied to the device when the round graph is called.
 
     ``shard_multiple`` (the engine's ``execution.mesh`` data-axis size)
     asserts the stacked width divides the device mesh: an indivisible
@@ -347,33 +435,57 @@ def stacked_round_batches(
     for i, nd in enumerate(num_docs):
         groups.setdefault((int(nd), min(batch_size, int(nd))), []).append(i)
 
-    draws = []
-    with spans.span(spans.DRAW):
-        for (nd, n), members in groups.items():
-            fn = _stacked_draw_fn(nd, n, e)
-            ids = jnp.asarray([int(client_ids[i]) for i in members],
-                              jnp.uint32)
-            idx_g, rng_g = fn(round_key, ids)
-            draws.append((n, members, np.asarray(idx_g),     # (G, E, n)
-                          np.asarray(rng_g, np.uint32)))     # (G, E, 2)
+    # on the resident path every array lives on the rows' device
+    with (jax.default_device(resident.device) if resident is not None
+          else contextlib.nullcontext()):
+        draws = []
+        with spans.span(spans.DRAW):
+            for (nd, n), members in groups.items():
+                fn = _stacked_draw_fn(nd, n, e)
+                ids = jnp.asarray([int(client_ids[i]) for i in members],
+                                  jnp.uint32)
+                idx_g, rng_g = fn(round_key, ids)  # (G, E, n), (G, E, 2)
+                if resident is None:
+                    idx_g, rng_g = np.asarray(idx_g), np.asarray(rng_g,
+                                                                 np.uint32)
+                draws.append((n, members, idx_g, rng_g))
 
-    # zero pages: the fill below touches them, inside its span
-    stacked: Dict[str, np.ndarray] = {
-        key: np.zeros((k_stack, e, p) + v.shape[1:],
-                      np.asarray(v).dtype)
-        for key, v in datas[0].items()
-    }
-    stacked["doc_mask"] = np.zeros((k_stack, e, p), np.float32)
-    stacked["rng"] = np.zeros((k_stack, e, 2), np.uint32)
-    counts = np.zeros((k_stack, e), np.float32)
-    with spans.span(spans.GATHER,
-                    bytes=sum(a.nbytes for a in stacked.values())):
-        for n, members, idx_g, rng_g in draws:
-            for g, i in enumerate(members):
-                for key, v in datas[i].items():
-                    # one (E, n)-index gather per (client, key)
-                    stacked[key][i, :, :n] = np.asarray(v)[idx_g[g]]
-                stacked["doc_mask"][i, :, :n] = 1.0
-                stacked["rng"][i] = rng_g[g]
-                counts[i, :] = n
+        doc_mask = np.zeros((k_stack, e, p), np.float32)
+        counts = np.zeros((k_stack, e), np.float32)
+        # the (K, E, P, ...) keys and doc_mask, and the (K, E, 2) rng
+        nbytes = k_stack * e * (p * (sum(
+            math.prod(np.shape(v)[1:]) * np.asarray(v).dtype.itemsize
+            for v in datas[0].values()) + 4) + 8)
+        with spans.span(spans.GATHER, bytes=nbytes,
+                        device=int(resident is not None)):
+            for n, members, _, _ in draws:
+                doc_mask[members, :, :n] = 1.0
+                counts[members] = n
+            if resident is not None:
+                # rows past a draw size and padded cohort rows read the
+                # zero row
+                zero = next(iter(resident.rows.values())).shape[0] - 1
+                index = jnp.full((k_stack, e, p), zero, jnp.int32)
+                rng = jnp.zeros((k_stack, e, 2), jnp.uint32)
+                for _, members, idx_g, rng_g in draws:
+                    index, rng = _scatter_draws(
+                        index, rng, idx_g, rng_g,
+                        np.asarray(members, np.int32),
+                        resident.offsets[[int(client_ids[i])
+                                          for i in members]])
+                stacked = _resident_gather(resident.rows, index,
+                                           shapes=resident.shapes)
+                stacked["rng"] = rng
+            else:
+                stacked = {key: np.zeros((k_stack, e, p) + np.shape(v)[1:],
+                                         np.asarray(v).dtype)
+                           for key, v in datas[0].items()}
+                stacked["rng"] = np.zeros((k_stack, e, 2), np.uint32)
+                for n, members, idx_g, rng_g in draws:
+                    for g, i in enumerate(members):
+                        for key, v in datas[i].items():
+                            # one (E, n)-index gather per (client, key)
+                            stacked[key][i, :, :n] = np.asarray(v)[idx_g[g]]
+                        stacked["rng"][i] = rng_g[g]
+    stacked["doc_mask"] = doc_mask
     return stacked, counts

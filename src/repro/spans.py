@@ -15,8 +15,11 @@ import jax
 
 # vmap round, in order (core/engine.py ``_round_vmap``,
 # data/federated_split.py ``stacked_round_batches``)
-DRAW = "round/draw"           # the jitted cohort index draws and their reads
-GATHER = "round/gather"       # numpy fill of the (K, E, P, ...) cohort arrays
+DRAW = "round/draw"           # the jitted cohort index draws (host fill: reads)
+# the (K, E, P, ...) cohort arrays, count ``bytes``; count ``device`` 1: the
+# enqueue of their gather from the clients' rows on the device, 0: their
+# numpy fill on the host
+GATHER = "round/gather"
 DISPATCH = "round/dispatch"   # the fused call's enqueue; the copy follows
 FETCH = "round/fetch"         # scalar and loss reads, the round's record
 HOST_SPANS = (DRAW, GATHER, DISPATCH, FETCH)

@@ -27,37 +27,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.api import (DataSpec, ExecutionSpec, Federation, FederationSpec,
-                       MeshSpec, ModelSpec, ScheduleSpec, build_corpus,
+from repro.api import (Federation, FederationSpec, MeshSpec, build_corpus,
                        spec_replace)
 from repro.core.transforms import pairwise_mask_stack
 from repro.data.federated_split import stacked_round_batches
 from repro.parallel import sharding
-from conftest import max_param_dev
+from conftest import max_param_dev, tiny_spec as _spec
 
 _max_dev = max_param_dev
-
-
-def _spec(num_clients=8, mesh=None, **overrides):
-    # lr and corpus seed chosen so the tiny federation CONVERGES over
-    # the test horizon: a diverging model grows params without bound and
-    # turns the absolute 1e-5 parity bound into noise measurement (at
-    # lr 1e-3 the corpora of data seeds 0, 3 and 5 blow up to inf by
-    # round 3; seed 1 trains down smoothly at L=8 and L=16)
-    base = FederationSpec(
-        model=ModelSpec(vocab=128, topics=4, hidden=16),
-        data=DataSpec(num_clients=num_clients, docs_per_node=40,
-                      val_docs_per_node=8, seed=1),
-        schedule=ScheduleSpec(rounds=3),
-        execution=ExecutionSpec(
-            exec_mode="vmap", batch_size=16, learning_rate=1e-3,
-            mesh=MeshSpec.from_value(mesh) if mesh is not None else None))
-    return spec_replace(base, overrides) if overrides else base
-
-
-@pytest.fixture(scope="module")
-def corpus8():
-    return build_corpus(_spec())
 
 
 @pytest.fixture(scope="module")

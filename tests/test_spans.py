@@ -6,6 +6,7 @@ import pytest
 
 from repro import spans
 from repro.core.transforms import TRANSFORMS
+from conftest import tiny_spec
 
 
 def test_every_registry_transform_has_a_scope():
@@ -32,3 +33,46 @@ def test_spans_and_scopes_leave_results_alone():
     assert float(got) == float(jnp.sin(x).sum())
     hlo = jax.jit(f).lower(x).as_text(debug_info=True)
     assert "aggregate" in hlo
+
+
+@pytest.mark.parametrize("where, device", [
+    ("one-device", 1), ("one-device-fits", 1), ("mesh", 0),
+    ("one-device-too-small", 0)])
+def test_resident_corpus_placement(where, device, monkeypatch, corpus8):
+    """From its second round a single-device vmap engine gathers its
+    cohorts on the device when the corpus, the cohort and the larger of
+    the round program's and the gather's temporaries fit in what the
+    device reports free (or it reports no limit); a mesh engine, or a
+    device with room for only the corpus and the cohort, keeps the host
+    fill.  Reading the round program's temporaries costs no trace."""
+    from repro.api import Federation
+    from repro.data.federated_split import row_nbytes
+    spec = tiny_spec(mesh={"data": 1}) if where == "mesh" else tiny_spec()
+    fed = Federation.from_spec(spec, corpus=corpus8)
+    eng = fed.engine
+    k_e_p = eng.scheduler.clients_per_round * eng._e_max * eng.batch_size
+    row = row_nbytes(eng.clients[0].data)
+    used = 1 << 30
+    room = {"one-device-fits": 1 << 30,
+            # the rows and the cohort (its bow, doc_mask and rng), no
+            # temporaries
+            "one-device-too-small":
+            row * (sum(c.num_docs for c in eng.clients) + 1)
+            + k_e_p * (eng.clients[0].data["bow"].itemsize
+                       * eng.clients[0].data["bow"].shape[1] + 4)
+            + k_e_p // eng.batch_size * 8}.get(where)
+    if room is not None:
+        monkeypatch.setattr(
+            type(jax.devices()[0]), "memory_stats",
+            lambda self: {"bytes_limit": used + room, "bytes_in_use": used})
+    seen = []
+    real = spans.span
+
+    def span(name, **counts):
+        if name == spans.GATHER:
+            seen.append(counts["device"])
+        return real(name, **counts)
+    monkeypatch.setattr(spans, "span", span)
+    fed.run(rounds=3)
+    assert seen == [0, device, device]
+    assert set(eng.trace_counts.values()) == {1}

@@ -31,7 +31,7 @@ from repro.core.ntm import prodlda
 from repro.core.protocol import ClientState, FederatedTrainer, FedAvgTrainer
 from repro.core.rounds import RoundEngine
 from repro.data.federated_split import stacked_round_batches
-from conftest import make_tiny_federation, max_param_dev
+from conftest import make_tiny_federation, max_param_dev, tiny_spec
 
 TOL = 1e-5
 # single home for the deviation metric + tiny federation: tests/conftest.py
@@ -247,6 +247,120 @@ def test_stacked_batches_bitwise_match_loop_iterator():
                 (np.arange(16) < n).astype(np.float32))
             np.testing.assert_array_equal(
                 stacked["rng"][i, s], np.asarray(batch["rng"], np.uint32))
+
+
+# ---------------------------------------------------------------------------
+# the resident corpus: the cohort gathered on the device, byte for byte
+# ---------------------------------------------------------------------------
+RESIDENT_CASES = {
+    # (docs per client, RoundConfig kwargs, an int token key as well)
+    "equal-sizes": ((48, 48, 48), {}, False),
+    # n < P = 16 for one client: three draw groups, zero rows past n
+    "mixed-sizes": ((40, 9, 17), {}, False),
+    # client 1 leaves at round 2: the cohort is padded back to K = 3
+    "padded-cohort": ((40, 9, 17), dict(client_leave_round=(0, 2, 0)), False),
+    "int-token-key": ((40, 9, 17), {}, True),
+}
+RESIDENT_REGIMES = {
+    "sync": {},
+    "straggler": dict(straggler_prob=0.6, max_staleness=3,
+                      staleness_decay=0.5),
+}
+
+
+@pytest.mark.parametrize("regime", sorted(RESIDENT_REGIMES))
+@pytest.mark.parametrize("case", sorted(RESIDENT_CASES))
+def test_resident_gather_matches_host_fill(case, regime, monkeypatch):
+    """With the clients' rows on the device (placed at the first
+    round's dispatch, so from round 2 on) the engine's cohort arrays,
+    counts and doc_mask are the host fill's, byte for byte, and so are
+    the params after 4 rounds; the round program compiles once across
+    the switch, and once where reading its temporaries for the fit rule
+    keeps the host fill on a device that reports too little memory."""
+    import repro.core.engine as engine_mod
+    docs, rc_kw, tokens = RESIDENT_CASES[case]
+    cfg, loss, loss_sum, init, clients = _make_setup(docs=docs)
+    if tokens:
+        gen = np.random.default_rng(1)
+        for c in clients:
+            c.data["tokens"] = gen.integers(
+                0, 1000, (c.num_docs, 8)).astype(np.int32)
+    fed = FederatedConfig(num_clients=3, learning_rate=1e-2, max_rounds=4,
+                          rel_tol=0.0)
+    rc = RoundConfig(**rc_kw, **RESIDENT_REGIMES[regime])
+    real = engine_mod.stacked_round_batches
+    runs = {}
+    for resident in (True, False):
+        if not resident:
+            monkeypatch.setattr(type(jax.devices()[0]), "memory_stats",
+                                lambda self: {"bytes_limit": 1,
+                                              "bytes_in_use": 0})
+        seen = []
+
+        def record(*a, **kw):
+            stacked, counts = real(*a, **kw)
+            seen.append((stacked, counts))
+            return stacked, counts
+        monkeypatch.setattr(engine_mod, "stacked_round_batches", record)
+        eng = RoundEngine(loss, init, clients, fed, rc, batch_size=16,
+                          exec_mode="vmap", loss_sum_fn=loss_sum)
+        compiles = []
+
+        def on(event, _, fun_name="", **__):
+            if event.endswith("backend_compile_duration") \
+                    and "fused_" in fun_name:
+                compiles.append(fun_name)
+        jax.monitoring.register_event_duration_secs_listener(on)
+        try:
+            for r in range(4):
+                eng.round(seed=r)
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on)
+        assert [isinstance(st["bow"], np.ndarray) for st, _ in seen] \
+            == [True] + [not resident] * 3
+        assert len(compiles) == 1, compiles
+        runs[resident] = seen, eng.params
+    (dev_rounds, dev_params), (host_rounds, host_params) = \
+        runs[True], runs[False]
+    for (dev, dev_counts), (host, host_counts) in zip(dev_rounds,
+                                                      host_rounds):
+        assert sorted(dev) == sorted(host)
+        for key in host:
+            a, b = np.asarray(dev[key]), np.asarray(host[key])
+            assert (a.dtype, a.shape) == (b.dtype, b.shape), key
+            assert a.tobytes() == b.tobytes(), key
+        assert dev_counts.tobytes() == host_counts.tobytes()
+    if case == "padded-cohort":
+        assert host_counts[-1].sum() == 0           # the padded row
+    jax.tree_util.tree_map(np.testing.assert_array_equal, dev_params,
+                           host_params)
+
+
+def test_resident_gather_compiles_once_over_changing_cohorts(monkeypatch):
+    """Cohorts of a quantity-skewed population change their client sizes
+    and draw groups every round; the resident gather still compiles
+    once."""
+    import repro.core.engine as engine_mod
+    from repro.api import Federation, build_corpus
+    from repro.data import federated_split as fs
+    spec = tiny_spec(**{"data.partition": "quantity_skew(0.5)",
+                        "schedule.clients_per_round": 3,
+                        "schedule.rounds": 8})
+    fed = Federation.from_spec(spec, corpus=build_corpus(spec))
+    real, cohorts = engine_mod.stacked_round_batches, []
+
+    def record(datas, num_docs, *a, **kw):
+        cohorts.append(tuple(num_docs))
+        return real(datas, num_docs, *a, **kw)
+    monkeypatch.setattr(engine_mod, "stacked_round_batches", record)
+    fs._resident_gather.clear_cache()
+    compiles = []
+    for _ in range(8):
+        fed.step()
+        compiles.append(fs._resident_gather._cache_size())
+    assert fed.engine._corpus is not None
+    assert len(set(cohorts[1:])) >= 4       # cohorts of other sizes
+    assert compiles == [0] + [1] * 7
 
 
 # The hypothesis fuzz layer over random (L, K, E, vocab, topics,
