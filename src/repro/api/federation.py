@@ -278,7 +278,10 @@ class Federation:
         """``model.family='lm'`` wiring: registry model bundle + token
         corpus, same override surface as the NTM path."""
         from repro.models.registry import build_model
-        bundle = build_model(cfg, dtype=jnp.float32)
+        # a published model computes as its config states (bf16
+        # activations over fp32 parameters); the CPU presets in fp32
+        bundle = build_model(cfg, dtype=None if spec.model.published
+                             else jnp.float32)
         if clients is None:
             if corpus is None:
                 corpus = build_lm_corpus(spec)

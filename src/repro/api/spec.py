@@ -233,6 +233,13 @@ class ModelSpec:
       (``0`` = keep the reduced default), and the NTM-only
       ``topics``/``hidden`` must stay at their defaults — fields are
       never silently dropped.
+
+      ``published=True`` starts from the arch's published config instead,
+      at every width, and states one chip's share of a deployment:
+      ``layers`` (the pipeline stage's depth), ``ep_size`` (the experts
+      split over that many chips, this one holding the first share) and
+      ``vocab`` (the vocabulary slice, ids ``0..vocab-1``); ``width`` is
+      refused there.
     """
     family: str = "ntm"
     vocab: int = 400
@@ -243,6 +250,8 @@ class ModelSpec:
     layers: int = 0             # 0 = the arch's reduced() layer count
     width: int = 0              # d_model override; 0 = reduced default
     seq_len: int = 0            # tokens per document; 0 = 32
+    published: bool = False     # the published widths, not reduced()
+    ep_size: int = 0            # chips sharing each MoE layer; 0 = all held
 
     def _validate(self) -> None:
         _require(self.family in ("ntm", "lm"),
@@ -256,12 +265,15 @@ class ModelSpec:
         _check_int(self.layers, "model.layers", 0)
         _check_int(self.width, "model.width", 0)
         _check_int(self.seq_len, "model.seq_len", 0)
+        _check_bool(self.published, "model.published")
+        _check_int(self.ep_size, "model.ep_size", 0)
         if self.family == "ntm":
             _require(self.arch == "" and self.layers == 0
-                     and self.width == 0 and self.seq_len == 0,
-                     "model.arch/layers/width/seq_len are LM-only "
-                     "fields — set model.family='lm' to use them; "
-                     "fields are never silently dropped")
+                     and self.width == 0 and self.seq_len == 0
+                     and not self.published and self.ep_size == 0,
+                     "model.arch/layers/width/seq_len/published/ep_size "
+                     "are LM-only fields — set model.family='lm' to use "
+                     "them; fields are never silently dropped")
             return
         # family == "lm"
         from repro.configs import ARCHS
@@ -290,6 +302,30 @@ class ModelSpec:
         if self.seq_len:
             _require(self.seq_len >= 2,
                      f"model.seq_len must be >= 2, got {self.seq_len}")
+        if not self.published:
+            _require(self.ep_size == 0,
+                     "model.ep_size states a published model's expert "
+                     "share — set model.published=True; fields are never "
+                     "silently dropped")
+            return
+        cfg = ARCHS[self.arch]
+        _require(self.width == 0,
+                 "model.width resizes a reduced() preset; a published "
+                 "model keeps every published width")
+        _require(self.vocab <= cfg.vocab_size,
+                 f"model.vocab={self.vocab} is not a slice of "
+                 f"{self.arch}'s {cfg.vocab_size}-id vocabulary")
+        _require(self.layers == 0
+                 or cfg.first_k_dense < self.layers <= cfg.num_layers,
+                 f"model.layers={self.layers} must keep every leading "
+                 f"dense layer ({cfg.first_k_dense}) and one more, and at "
+                 f"most {self.arch}'s {cfg.num_layers}")
+        if self.ep_size:
+            _require(cfg.moe.routing == "noaux_tc"
+                     and cfg.moe.num_experts % self.ep_size == 0,
+                     f"model.ep_size={self.ep_size} must divide "
+                     f"{self.arch}'s routed experts, under the held-expert "
+                     "layer (noaux_tc routing)")
 
 
 @dataclass(frozen=True)
@@ -736,6 +772,7 @@ class FederationSpec:
                      "the flag under model.family='lm' instead of having "
                      "it silently ignored")
         if "secure" in self.transforms.names:
+            self._refuse_secure_under_scan()
             _require("precision" not in self.transforms.names,
                      "the 'secure' transform is incompatible with "
                      "'precision' (bf16 messages): pairwise masks cancel "
@@ -808,6 +845,35 @@ class FederationSpec:
                      "trees shard over the same axis; resize L or the "
                      "mesh")
 
+    def _refuse_secure_under_scan(self) -> None:
+        """The engine's cohort schedule (``engine.scan_clients``) on this
+        spec's parameter bytes, cohort width and the default device's
+        free memory: pairwise masks span the cohort, so ``secure`` is
+        refused where the round would scan its clients."""
+        ex = self.execution
+        if ex.exec_mode != "vmap" or ex.mesh is not None:
+            return
+        import jax
+        from repro.core.engine import device_free_bytes, scan_clients
+        if self.model.family == "lm":
+            from repro.models.transformer import init_params
+        else:
+            from repro.core.ntm.prodlda import init_params
+        cfg = self.to_model_config()
+        shapes = jax.eval_shape(lambda k: init_params(k, cfg),
+                                jax.random.PRNGKey(0))
+        nbytes = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree_util.tree_leaves(shapes))
+        L = self.data.num_clients
+        k = min(self.schedule.clients_per_round or L, L)
+        _require(not scan_clients(nbytes, k,
+                                  device_free_bytes(jax.devices()[0])),
+                 "the 'secure' transform is refused under the client-scan "
+                 f"round: the vmapped cohort ({k} clients of "
+                 f"{nbytes / 1e9:.3g} GB parameters) does not fit this "
+                 "device, and pairwise masks span the cohort — they "
+                 "cancel only in one stacked combine")
+
     # -- resolved (cross-section) defaults --------------------------------
     @property
     def resolved_data_seed(self) -> int:
@@ -859,9 +925,21 @@ class FederationSpec:
         """The arch's CPU-scale ``reduced()`` config with the spec's
         size overrides — the federated analogue of the launcher's
         ``--reduced`` path, so every registry family lowers the same
-        way it does in the arch smoke tests."""
+        way it does in the arch smoke tests.  ``model.published`` starts
+        from the published config and takes the chip's share: depth,
+        experts held (``ep_size``) and the vocabulary slice."""
         from repro.configs import get_config
         m = self.model
+        if m.published:
+            cfg = get_config(m.arch)
+            kw = {"name": self.name or f"fed-{m.arch}",
+                  "vocab_size": m.vocab,
+                  "num_layers": m.layers or cfg.num_layers,
+                  "max_seq_len": max(cfg.max_seq_len,
+                                     self.resolved_seq_len + 1)}
+            if m.ep_size:
+                kw["moe"] = dataclasses.replace(cfg.moe, ep_size=m.ep_size)
+            return dataclasses.replace(cfg, **kw)
         cfg = get_config(m.arch).reduced()
         kw: Dict[str, Any] = {
             "name": self.name or f"fed-{m.arch}",

@@ -17,6 +17,7 @@ from repro.configs.llama4_maverick_400b import CONFIG as _llama4
 from repro.configs.qwen3_moe_235b import CONFIG as _qwen3
 from repro.configs.minicpm3_4b import CONFIG as _minicpm3
 from repro.configs.mamba2_1_3b import CONFIG as _mamba2
+from repro.configs.moonlight_16b_a3b import CONFIG as _moonlight
 from repro.configs.prodlda_synthetic import CONFIG as _prodlda
 from repro.configs.ctm_s2orc import CONFIG as _ctm
 
@@ -40,7 +41,13 @@ PAPER_ARCHS = {
     "ctm-s2orc": _ctm,
 }
 
-ARCHS = {**ASSIGNED_ARCHS, **PAPER_ARCHS}
+# Published models federated at their widths, as one chip's share of a
+# stated deployment (docs/lm_federation.md).
+PUBLISHED_ARCHS = {
+    "moonlight-16b-a3b": _moonlight,
+}
+
+ARCHS = {**ASSIGNED_ARCHS, **PAPER_ARCHS, **PUBLISHED_ARCHS}
 
 
 def get_config(arch: str) -> ModelConfig:
@@ -70,7 +77,8 @@ def applicable_shapes(cfg: ModelConfig) -> list[str]:
 
 
 __all__ = [
-    "ARCHS", "ASSIGNED_ARCHS", "PAPER_ARCHS", "INPUT_SHAPES",
+    "ARCHS", "ASSIGNED_ARCHS", "PAPER_ARCHS", "PUBLISHED_ARCHS",
+    "INPUT_SHAPES",
     "get_config", "get_shape", "applicable_shapes",
     "ModelConfig", "MoEConfig", "SSMConfig", "FederatedConfig", "RunConfig",
     "ShapeConfig", "TRAIN_4K", "PREFILL_32K", "DECODE_32K", "LONG_500K",

@@ -54,6 +54,22 @@ class MoEConfig:
     # position-in-expert assignment is shard-local (16 = the production
     # data axis; automatically reduced to divide small test batches)
     num_groups: int = 16
+    # "capacity": softmax router, Switch aux loss, per-expert capacity
+    # (tokens past it dropped).  "noaux_tc": DeepSeek-V3's router —
+    # sigmoid scores over all experts, top-k chosen on scores plus a
+    # selection bias, gates the chosen scores normalised to sum 1 times
+    # ``routed_scaling_factor``, no aux loss, no dropped token
+    routing: str = "capacity"
+    routed_scaling_factor: float = 1.0
+    # expert parallelism: the experts are split into ``ep_size`` equal
+    # contiguous shares and this chip holds share ``ep_rank`` (noaux_tc
+    # routing only; the router still scores all ``num_experts``)
+    ep_size: int = 1
+    ep_rank: int = 0
+
+    @property
+    def num_held(self) -> int:
+        return self.num_experts // max(self.ep_size, 1)
 
 
 @dataclass(frozen=True)
@@ -93,7 +109,7 @@ class ModelConfig:
     # MLA (minicpm3 / deepseek-style multi-head latent attention)
     use_mla: bool = False
     mla_kv_lora_rank: int = 256
-    mla_q_lora_rank: int = 768
+    mla_q_lora_rank: int = 768   # 0 = a direct query projection (no w_dq)
     mla_rope_head_dim: int = 32
     # decode-time weight absorption (DeepSeek-V2 serving optimization):
     # attention scores/combine run directly in the latent space, the
@@ -113,6 +129,11 @@ class ModelConfig:
     frontend_embed_dim: int = 0
 
     moe: MoEConfig = field(default_factory=MoEConfig)
+    # DeepSeek-style leading dense layers (``first_k_dense_replace``): the
+    # first ``first_k_dense`` layers are dense SwiGLU of width
+    # ``dense_d_ff`` (0 = ``d_ff``), outside the scanned MoE units
+    first_k_dense: int = 0
+    dense_d_ff: int = 0
     ssm: SSMConfig = field(default_factory=SSMConfig)
     # hymba: fraction of "heads" that are SSD heads in the parallel hybrid
     # block; attention and mamba run in parallel and are mean-fused.
@@ -179,8 +200,9 @@ class ModelConfig:
             if self.use_mla:
                 qr, kr, rr = self.mla_q_lora_rank, self.mla_kv_lora_rank, \
                     self.mla_rope_head_dim
-                per_layer += d * qr + qr * nq * (hd + rr)
-                per_layer += d * (kr + rr) + kr * nq * (hd + hd)
+                per_layer += d * qr + qr * nq * (hd + rr) + qr if qr \
+                    else d * nq * (hd + rr)
+                per_layer += d * (kr + rr) + kr * nq * (hd + hd) + kr
                 per_layer += nq * hd * d
             else:
                 per_layer += d * (nq * hd) + 2 * d * (nkv * hd) + (nq * hd) * d
@@ -194,13 +216,18 @@ class ModelConfig:
                     + d_in * s.conv_width + d_in * d + 2 * nheads
             # FFN
             if self.kind == MOE and self.moe.num_experts:
-                e = self.moe.num_experts + self.moe.num_shared_experts
-                ffn = 3 * d * self.d_ff
-                per_layer += e * ffn + d * self.moe.num_experts  # + router
+                e = self.moe.num_held + self.moe.num_shared_experts
+                ffn = e * 3 * d * self.d_ff \
+                    + d * self.moe.num_experts  # + router
+                if self.moe.routing == "noaux_tc":
+                    ffn += self.moe.num_experts  # selection bias
             else:
                 mult = 3 if self.activation == "swiglu" else 2
-                per_layer += mult * d * self.d_ff
-            per_layer += 2 * d  # norms
+                ffn = mult * d * self.d_ff
+            per_layer += ffn + 2 * d  # + norms
+            # the leading dense layers hold a SwiGLU in place of the FFN
+            n += self.first_k_dense * (
+                3 * d * (self.dense_d_ff or self.d_ff) - ffn)
         n += self.num_layers * per_layer + d
         return n
 
@@ -246,8 +273,10 @@ class ModelConfig:
                                 head_dim=32, chunk_size=64)
         if self.use_mla:
             kw["mla_kv_lora_rank"] = 32
-            kw["mla_q_lora_rank"] = 48
+            kw["mla_q_lora_rank"] = 48 if self.mla_q_lora_rank else 0
             kw["mla_rope_head_dim"] = 16
+        if self.dense_d_ff:
+            kw["dense_d_ff"] = min(self.dense_d_ff, 512)
         if self.use_mrope:
             hd = d // nh
             kw["mrope_sections"] = (hd // 2 - 2 * (hd // 8), hd // 8, hd // 8)

@@ -24,7 +24,10 @@ duplicated per class:
     tested against;
   * ``"vmap"`` stacks the cohort's minibatches on a leading client axis
     and runs all K local-update loops, the Eq. (2) combine and the
-    server optimizer in ONE jitted graph.  With stragglers enabled the
+    server optimizer in ONE jitted graph.  Where the vmapped cohort
+    would not fit the device (:func:`scan_clients`), the synchronous
+    graph scans the K clients instead, summing the combine's numerator
+    as it goes.  With stragglers enabled the
     combine runs through an IN-GRAPH fixed-capacity ring buffer of
     stacked deltas (age counters + weights as arrays) instead of the
     host-side pending list — the straggler regime is now exactly as
@@ -198,13 +201,13 @@ def masked_mean_loss(loss_fn, loss_sum_fn=None):
     """
     if loss_sum_fn is not None:
         def mean_loss(params, batch):
-            s, n = loss_sum_fn(params, batch)
-            return s / jnp.maximum(n, 1.0)
+            s, n, *counters = loss_sum_fn(params, batch)
+            return s / jnp.maximum(n, 1.0), counters[0] if counters else {}
         return mean_loss
 
     def mean_loss(params, batch):
         return loss_fn(params, {k: v for k, v in batch.items()
-                                if k != "doc_mask"})
+                                if k != "doc_mask"}), {}
     return mean_loss
 
 
@@ -228,6 +231,25 @@ def _rel_change(old: Pytree, new: Pytree) -> jnp.ndarray:
     num = global_norm(jax.tree_util.tree_map(lambda a, b: a - b, old, new))
     den = jnp.maximum(global_norm(old), 1e-12)
     return num / den
+
+
+def device_free_bytes(device) -> Optional[int]:
+    """``bytes_limit`` less ``bytes_in_use`` as ``device`` reports them,
+    or None where it reports no limit (the CPU)."""
+    stats = device.memory_stats() or {}
+    if "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+
+
+def scan_clients(param_bytes: int, clients: int,
+                 free_bytes: Optional[int]) -> bool:
+    """The synchronous round's cohort schedule: True scans the K clients
+    one by one, False vmaps them.  The vmapped cohort holds, for each
+    client, its local parameters, their gradient and its message,
+    ``3 * K * param_bytes``; the round vmaps when that fits in what the
+    device reports free, or when it reports no limit."""
+    return free_bytes is not None and 3 * clients * param_bytes > free_bytes
 
 
 # ---------------------------------------------------------------------------
@@ -568,6 +590,7 @@ class FederationEngine:
         self._fused_stale = None
         self._deliver_only = None
         self._zero_stacked = None      # all-padded round template (vmap)
+        self._scan = False             # scan the cohort (scan_clients)
         self._corpus = None            # the clients' rows on the device
         self._corpus_open = True       # decided at the first dispatch
         # one entry per TRACE of each fused graph (the bodies bump it at
@@ -775,22 +798,23 @@ class FederationEngine:
     def _build_client_update(self):
         """The vmappable E-epoch local update for ONE client."""
         lr = self.fed.learning_rate
-        grad_fn = jax.value_and_grad(self._mean_loss)
+        grad_fn = jax.value_and_grad(self._mean_loss, has_aux=True)
         tmap = jax.tree_util.tree_map
         e_max, gate = self._e_max, self._hetero
 
         if self.message == "grad":
             def client_update(params, batches, n_epochs):
                 # single-minibatch gradient message (E axis is size 1)
-                loss, g = grad_fn(params, tmap(lambda v: v[0], batches))
-                return g, loss[None]
+                (loss, counters), g = grad_fn(params,
+                                              tmap(lambda v: v[0], batches))
+                return g, loss[None], counters
             return client_update
 
         def client_update(params, batches, n_epochs):
             # batches: pytree of (E, ...) leaves — one client's epoch stack
             def epoch(local, xs):
                 b, s = xs
-                loss, grads = grad_fn(local, b)
+                (loss, counters), grads = grad_fn(local, b)
                 stepped = tmap(lambda p, g: p - lr * g.astype(p.dtype),
                                local, grads)
                 if gate:
@@ -801,10 +825,13 @@ class FederationEngine:
                     stepped = tmap(lambda a, b_: jnp.where(keep, b_, a),
                                    local, stepped)
                     loss = jnp.where(keep, loss, 0.0)
-                return stepped, loss
-            local, losses = jax.lax.scan(
+                    counters = tmap(lambda c: jnp.where(keep, c, 0),
+                                    counters)
+                return stepped, (loss, counters)
+            local, (losses, counters) = jax.lax.scan(
                 epoch, params, (batches, jnp.arange(e_max)))
-            return tmap(lambda a, b: b - a, params, local), losses
+            return (tmap(lambda a, b: b - a, params, local), losses,
+                    tmap(lambda c: c.sum(0), counters))
 
         return client_update
 
@@ -820,6 +847,8 @@ class FederationEngine:
         # static at trace time: selects the aggregation kernel backend
         # ("xla" keeps every expression below byte-identical to pre-PR-7)
         kb = self.kernel_backend
+        # static at trace time: the cohort schedule (scan_clients)
+        scan = self._scan
         # static at trace time: the ("data",)-axis device mesh (or None).
         # Sharded runs keep the SAME graphs below — inputs arrive with
         # the K/L/C axes row-sharded (in_shardings), the per-row stages
@@ -867,21 +896,56 @@ class FederationEngine:
                 return jax.vmap(client_update, in_axes=(None, 0, 0))(
                     params, stacked, e_counts)
 
+        def scanned_numerator(params, tstate, stacked, e_counts, w, ids,
+                              round_key):
+            """The client-scan schedule: each client's local update, the
+            transforms on its one-row stack, and its term of the Eq. (2)
+            numerator, one client at a time -> (numerator, tstate,
+            (K, E) losses, (K, ...) counters)."""
+            acc0 = tmap(lambda p: jnp.zeros(p.shape, jnp.float32), params)
+
+            def one(carry, xs):
+                acc, tstate = carry
+                batches, e_k, w_k, id_k = xs
+                with spans.scope(spans.LOCAL_UPDATE):
+                    msg, losses, counters = client_update(params, batches,
+                                                          e_k)
+                row, tstate = transform_stage(
+                    tmap(lambda m: m[None], msg), tstate, round_key,
+                    id_k[None], w_k[None])
+                with spans.scope(spans.AGGREGATE):
+                    acc = kops.fed_weighted_accumulate(
+                        acc, tmap(lambda m: m[0], row), w_k, backend=kb)
+                return (acc, tstate), (losses, counters)
+
+            (acc, tstate), (losses, counters) = jax.lax.scan(
+                one, (acc0, tstate), (stacked, e_counts, w, ids))
+            return acc, tstate, losses, counters
+
         def fused_sync(params, server_state, tstate, stacked, e_counts,
                        weights, ids, round_key, round_idx):
             """messages -> transforms -> Eq. (2) combine -> server
             update, zero host hops (the synchronous fast path).  The
             update is gated on any positive weight: an all-padded
             (empty) cohort leaves params AND server state untouched —
-            momentum must not decay on a no-arrival round."""
+            momentum must not decay on a no-arrival round.  Under the
+            client-scan schedule (``self._scan``) the combine's numerator
+            is summed client by client; the rest is the same."""
             counts["fused_sync"] = counts.get("fused_sync", 0) + 1
-            msgs, losses = stacked_messages(params, stacked, e_counts)
-            msgs = pin_rows(msgs)
             w = weights.astype(jnp.float32)
-            msgs, tstate = transform_stage(msgs, tstate, round_key, ids, w)
+            if scan:
+                num, tstate, losses, counters = scanned_numerator(
+                    params, tstate, stacked, e_counts, w, ids, round_key)
+            else:
+                msgs, losses, counters = stacked_messages(params, stacked,
+                                                          e_counts)
+                msgs = pin_rows(msgs)
+                msgs, tstate = transform_stage(msgs, tstate, round_key,
+                                               ids, w)
             with spans.scope(spans.AGGREGATE):
-                bar = kops.fed_weighted_combine(msgs, w, backend=kb,
-                                                mesh=mesh)
+                bar = tmap(lambda a: a / jnp.maximum(w.sum(), 1e-12), num) \
+                    if scan else kops.fed_weighted_combine(
+                        msgs, w, backend=kb, mesh=mesh)
                 upd_p, upd_s = server_opt.apply(params, bar, server_state,
                                                 round_idx)
                 has = w.sum() > 0.0
@@ -890,7 +954,11 @@ class FederationEngine:
                 new_params, new_state = sel(params, upd_p), sel(server_state,
                                                                 upd_s)
                 rel = jnp.where(has, _rel_change(params, new_params), 0.0)
-            return new_params, new_state, tstate, losses, rel
+            # the model's counters of the positive-weight clients
+            counters = tmap(lambda c: jnp.sum(jnp.where(
+                (w > 0.0).reshape((-1,) + (1,) * (c.ndim - 1)), c, 0), 0),
+                counters)
+            return new_params, new_state, tstate, losses, rel, counters
 
         def ring_deliver(params, server_state, ring, round_idx,
                          fresh=None):
@@ -979,7 +1047,7 @@ class FederationEngine:
             (so no staleness age ever starts for them), and an
             all-padded cohort degenerates to a deliver-only round."""
             counts["fused_stale"] = counts.get("fused_stale", 0) + 1
-            msgs, losses = stacked_messages(params, stacked, e_counts)
+            msgs, losses, _ = stacked_messages(params, stacked, e_counts)
             msgs = pin_rows(msgs)
             w = weights.astype(jnp.float32)
             msgs, tstate = transform_stage(msgs, tstate, round_key, ids, w)
@@ -1048,7 +1116,7 @@ class FederationEngine:
             # (params, server_state, tstate, stacked, e_counts, weights,
             #  ids, round_key, round_idx)
             in_shardings=(rep, rep, row, row, row, row, row, rep, rep),
-            out_shardings=(rep, rep, row, row, rep))
+            out_shardings=(rep, rep, row, row, rep, rep))
         self._fused_stale = jax.jit(
             fused_stale, donate_argnums=(0, 1, 2, 3) if dn else (),
             # (params, server_state, tstate, ring, stacked, e_counts,
@@ -1090,6 +1158,28 @@ class FederationEngine:
         if self._ring is not None:
             self._ring = self._place(self._ring, rows=True)
 
+    def _choose_schedule(self) -> bool:
+        """:func:`scan_clients` on the sizes the engine observes: its
+        parameters' bytes, the cohort width K and what the device of its
+        state reports free.  A mesh keeps the vmap (its rows are
+        sharded).  A transform that spans the cohort (``secure``) is
+        refused under the scan: its masks cancel only in one stacked
+        combine."""
+        if self._mesh is not None:
+            return False
+        leaves = jax.tree_util.tree_leaves(self.params)
+        (device,) = leaves[0].devices()
+        scan = scan_clients(sum(x.nbytes for x in leaves),
+                            self.scheduler.clients_per_round,
+                            device_free_bytes(device))
+        if scan and any(n == "secure" for n, _ in self._transforms):
+            raise ValueError(
+                "the 'secure' transform is refused under the client-scan "
+                "round (a cohort too large to vmap on this device): its "
+                "pairwise masks span the cohort and cancel only in one "
+                "stacked combine")
+        return scan
+
     def _resident_corpus(self, fused, args, stacked):
         """Every client's rows placed on the device of the engine's state
         (``place_corpus``), or None: each cohort is then filled in host
@@ -1110,8 +1200,8 @@ class FederationEngine:
             return None
         datas = [c.data for c in self.clients]
         (device,) = jax.tree_util.tree_leaves(self.params)[0].devices()
-        stats = device.memory_stats() or {}
-        if "bytes_limit" in stats:
+        free = device_free_bytes(device)
+        if free is not None:
             row, docs = row_nbytes(datas[0]), sum(
                 len(next(iter(d.values()))) for d in datas)
             temp = getattr(fused.lower(*args).compile().memory_analysis(),
@@ -1119,7 +1209,7 @@ class FederationEngine:
             need = row * (docs + 1) + sum(
                 np.asarray(v).nbytes for v in stacked.values()) + max(
                 temp, row * np.size(stacked["doc_mask"]))
-            if need > stats["bytes_limit"] - stats.get("bytes_in_use", 0):
+            if need > free:
                 return None
         return place_corpus(datas, device)
 
@@ -1154,8 +1244,9 @@ class FederationEngine:
     def _round_vmap(self, r: int, round_key, cohort) -> Dict[str, float]:
         cohort = [int(l) for l in cohort]
         if self._fused_sync is None:
-            self._build_vmap_fns()
             self._own_state()
+            self._scan = self._choose_schedule()
+            self._build_vmap_fns()
         ri = np.int32(r)
         # fixed-K stacking: availability churn shrinks the cohort, the
         # stacked axis stays clients_per_round wide (zero-weight rows)
@@ -1217,10 +1308,12 @@ class FederationEngine:
         if self._corpus_open:
             self._corpus_open = False
             self._corpus = self._resident_corpus(fused, args, stacked)
-        with spans.span(spans.DISPATCH):
+        with spans.span(spans.DISPATCH, scan=int(self._scan)):
             out = fused(*args)
+        counters = {}
         if not self._stale_enabled:
-            self.params, self.server_state, self._tstate, losses, rel = out
+            (self.params, self.server_state, self._tstate, losses, rel,
+             counters) = out
             arrived, in_flight, n_sup = len(cohort), 0, 0
         else:
             (self.params, self.server_state, self._tstate, self._ring,
@@ -1245,7 +1338,11 @@ class FederationEngine:
                     "participants": len(cohort),
                     "arrived": arrived,
                     "superseded": superseded,
-                    "in_flight": in_flight}
+                    "in_flight": in_flight,
+                    # the model's counters summed over the round's clients
+                    # and steps (expert_tokens: pairs per held expert)
+                    **{k: np.asarray(v).tolist()
+                       for k, v in counters.items()}}
 
     # -- stopping ---------------------------------------------------------
     @staticmethod

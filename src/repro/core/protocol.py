@@ -55,7 +55,7 @@ def weighted_global_loss(loss_sum_fn: Callable[..., Tuple[jnp.ndarray,
                                                           jnp.ndarray]]):
     """Wrap a (sum_loss, count) fn into the Eq.-(2)-equivalent global mean."""
     def loss(params, batch, **kw):
-        s, n = loss_sum_fn(params, batch, **kw)
+        s, n = loss_sum_fn(params, batch, **kw)[:2]
         return s / jnp.maximum(n, 1.0)
     return loss
 
@@ -100,7 +100,7 @@ def make_federated_train_step(
                 lbatch["rng"] = local_rng
 
             def local_mean_loss(p):
-                s, n = loss_sum_fn(p, lbatch)
+                s, n = loss_sum_fn(p, lbatch)[:2]
                 return s / jnp.maximum(n, 1.0), n
 
             (loss, n_l), grads = jax.value_and_grad(

@@ -158,6 +158,27 @@ def fed_weighted_combine(tree, weights, *, backend: str = "xla",
                              tree, jnp.asarray(weights, jnp.float32))
 
 
+def fed_weighted_accumulate(acc, tree, weight, *, backend: str = "xla",
+                            interpret: bool | None = None):
+    """The client-scan round's running Eq. (2) numerator: per leaf
+    ``acc + w * x`` for ONE client's message ``tree`` and weight ``w``
+    (zero weight adds nothing, whatever the message holds).  Summed over
+    a cohort and divided by ``max(sum w, 1e-12)`` it is
+    :func:`fed_weighted_combine` up to fp32 summation order
+    (``ref.fed_accumulate_ref`` is the oracle)."""
+    _check_backend(backend)
+    w = jnp.asarray(weight, jnp.float32)
+    if backend == "xla":
+        return jax.tree_util.tree_map(
+            lambda a, x: a + jnp.where(w > 0.0, w * x.astype(jnp.float32),
+                                       0.0), acc, tree)
+    interpret = _auto_interpret() if interpret is None else interpret
+    return jax.tree_util.tree_map(
+        lambda a, x: a + fed_weighted_sum_pallas(
+            x.reshape(1, -1), w.reshape(1),
+            interpret=interpret).reshape(x.shape), acc, tree)
+
+
 def fed_weighted_sum(tree, coefs, *, backend: str = "xla",
                      interpret: bool | None = None, mesh=None):
     """NUMERATOR-only per-leaf ``sum_k c_k x_k`` over a stacked pytree —

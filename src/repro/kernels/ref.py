@@ -83,6 +83,15 @@ def fed_combine_ref(stacked, weights):
     return jnp.sum(wb * contrib, axis=0) / total
 
 
+def fed_accumulate_ref(acc, msg, weight):
+    """One client's term of the Eq. (2) numerator added to ``acc``: the
+    client-scan round's running sum.  A zero weight adds nothing, even to
+    a non-finite message; fp32 accumulation."""
+    w = jnp.asarray(weight, jnp.float32)
+    return acc.astype(jnp.float32) + jnp.where(
+        w > 0.0, w * msg.astype(jnp.float32), 0.0)
+
+
 def fed_topk_ef_ref(msgs, err_rows, k_keep: int):
     """Fused top-k select + error feedback over a ``(K, D)`` cohort.
 

@@ -18,6 +18,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.models.layers.init import dense_init
 from repro.models.layers.norms import rmsnorm, rmsnorm_init
 from repro.models.layers.rope import apply_rope
@@ -281,14 +282,18 @@ def gqa_decode(params, cfg, x, angles, *, cache_k, cache_v, pos):
 # MLA — multi-head latent attention (minicpm3-4b / deepseek-v2 style)
 # ---------------------------------------------------------------------------
 def mla_init(key, cfg):
+    """MLA weights; ``mla_q_lora_rank == 0`` (DeepSeek-V2-Lite, Moonlight:
+    ``q_lora_rank`` null) projects the query directly, ``w_q``."""
     d, hd = cfg.d_model, cfg.resolved_head_dim
     nq = cfg.num_heads
     qr, kr, rr = cfg.mla_q_lora_rank, cfg.mla_kv_lora_rank, cfg.mla_rope_head_dim
     ks = jax.random.split(key, 7)
+    q = {"w_dq": dense_init(ks[0], (d, qr)),
+         "q_norm": rmsnorm_init(qr),
+         "w_uq": dense_init(ks[1], (qr, nq * (hd + rr)))} if qr \
+        else {"w_q": dense_init(ks[0], (d, nq * (hd + rr)))}
     return {
-        "w_dq": dense_init(ks[0], (d, qr)),
-        "q_norm": rmsnorm_init(qr),
-        "w_uq": dense_init(ks[1], (qr, nq * (hd + rr))),
+        **q,
         "w_dkv": dense_init(ks[2], (d, kr)),
         "kv_norm": rmsnorm_init(kr),
         "w_kr": dense_init(ks[3], (d, rr)),
@@ -300,9 +305,12 @@ def mla_init(key, cfg):
 def _mla_q(params, cfg, x, angles):
     b, s, _ = x.shape
     nq, hd, rr = cfg.num_heads, cfg.resolved_head_dim, cfg.mla_rope_head_dim
-    cq = jnp.einsum("bsd,dr->bsr", x, params["w_dq"].astype(x.dtype))
-    cq = rmsnorm(params["q_norm"], cq, cfg.norm_eps)
-    q = jnp.einsum("bsr,re->bse", cq, params["w_uq"].astype(x.dtype))
+    if "w_q" in params:
+        q = jnp.einsum("bsd,de->bse", x, params["w_q"].astype(x.dtype))
+    else:
+        cq = jnp.einsum("bsd,dr->bsr", x, params["w_dq"].astype(x.dtype))
+        cq = rmsnorm(params["q_norm"], cq, cfg.norm_eps)
+        q = jnp.einsum("bsr,re->bse", cq, params["w_uq"].astype(x.dtype))
     q = q.reshape(b, s, nq, hd + rr)
     q_nope, q_rope = q[..., :hd], q[..., hd:]
     if angles is not None:
@@ -344,6 +352,12 @@ def _mla_attend(params, cfg, q_nope, q_rope, k_nope, k_rope, v, mask):
 
 
 def mla_full(params, cfg, x, angles, *, positions, causal=True):
+    with spans.scope(spans.ATTENTION_MLA):
+        return _mla_full(params, cfg, x, angles, positions=positions,
+                         causal=causal)
+
+
+def _mla_full(params, cfg, x, angles, *, positions, causal):
     q_nope, q_rope = _mla_q(params, cfg, x, angles)
     ckv, kr = _mla_kv_latent(params, cfg, x, angles)
     k_nope, v = _mla_expand_kv(params, cfg, ckv)

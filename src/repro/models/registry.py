@@ -19,8 +19,10 @@ class ModelBundle:
     init: Callable[..., Any]            # (key) -> params
     loss: Callable[..., Any]            # (params, batch) -> scalar loss
     forward: Callable[..., Any]         # (params, batch) -> model outputs
-    # (params, batch) -> (sum_loss, count): the mask-aware form the
-    # federated stacked path weights by (Eq. (2) sample counts)
+    # (params, batch) -> (sum_loss, count[, counters]): the mask-aware
+    # form the federated stacked path weights by (Eq. (2) sample counts);
+    # a model with per-round counters (noaux_tc MoE: expert_tokens)
+    # returns them as a dict third
     loss_sum: Optional[Callable[..., Any]] = None
     prefill: Optional[Callable[..., Any]] = None
     decode_step: Optional[Callable[..., Any]] = None

@@ -50,15 +50,15 @@ def _apply_norm(cfg, p, x):
     return rmsnorm(p, x, cfg.norm_eps)
 
 
-def _ffn_init(key, cfg, moe_layer: bool):
+def _ffn_init(key, cfg, moe_layer: bool, d_ff: int = 0):
     if moe_layer:
         return moe_lib.moe_init(key, cfg)
     if cfg.activation == "gelu":
         return gelu_mlp_init(key, cfg.d_model, cfg.d_ff)
-    return swiglu_init(key, cfg.d_model, cfg.d_ff)
+    return swiglu_init(key, cfg.d_model, d_ff or cfg.d_ff)
 
 
-def _layer_init(key, cfg: ModelConfig, moe_layer: bool):
+def _layer_init(key, cfg: ModelConfig, moe_layer: bool, d_ff: int = 0):
     d = cfg.d_model
     if cfg.kind == SSM:
         k1, _ = jax.random.split(key)
@@ -75,7 +75,7 @@ def _layer_init(key, cfg: ModelConfig, moe_layer: bool):
         "attn_norm": _norm_init(cfg, d),
         "mixer": mixer,
         "ffn_norm": _norm_init(cfg, d),
-        "ffn": _ffn_init(k2, cfg, moe_layer),
+        "ffn": _ffn_init(k2, cfg, moe_layer, d_ff),
     }
 
 
@@ -90,7 +90,7 @@ def _unit_layout(cfg: ModelConfig) -> Tuple[int, bool]:
 def init_params(key, cfg: ModelConfig) -> Dict[str, Any]:
     keys = jax.random.split(key, 8)
     per_unit, has_dense_sub = _unit_layout(cfg)
-    num_units = cfg.num_layers // per_unit
+    num_units = (cfg.num_layers - cfg.first_k_dense) // per_unit
 
     def one_unit(k):
         if has_dense_sub:
@@ -106,6 +106,13 @@ def init_params(key, cfg: ModelConfig) -> Dict[str, Any]:
         "layers": layers,
         "final_norm": _norm_init(cfg, cfg.d_model),
     }
+    if cfg.first_k_dense:
+        # leading dense layers (DeepSeek's first_k_dense_replace), a
+        # stack of their own ahead of the scanned units
+        params["dense_layers"] = jax.vmap(
+            lambda k: _layer_init(k, cfg, moe_layer=False,
+                                  d_ff=cfg.dense_d_ff))(
+            jax.random.split(keys[5], cfg.first_k_dense))
     if cfg.kind == AUDIO:
         params["frontend_proj"] = {
             "w": dense_init(keys[1], (cfg.frontend_embed_dim, cfg.d_model)),
@@ -191,46 +198,65 @@ def _angles_for(cfg, batch, positions):
 def _run_layers_full(params, cfg, x, angles, positions, *, causal,
                      want_cache: bool):
     per_unit, has_dense_sub = _unit_layout(cfg)
+    if want_cache:
+        _refuse_leading_dense(cfg)
 
-    def unit_fn(carry, lp):
+    def layer_fn(carry, lp):
         x, aux = carry
         x = constrain_batch(x)     # keep batch on the client/data axes
-        if has_dense_sub:
-            x, c1, a1 = _block_full(cfg, lp["dense_sub"], x, angles,
-                                    positions, causal=causal)
-            x, c2, a2 = _block_full(cfg, lp["moe_sub"], x, angles,
-                                    positions, causal=causal)
-            cache = (c1, c2)
-            aux = aux + a1 + a2
-        else:
-            x, cache, a = _block_full(cfg, lp, x, angles, positions,
-                                      causal=causal)
-            aux = aux + a
-        x = constrain_batch(x)
-        ys = cache if want_cache else None
-        return (x, aux), ys
+        x, cache, a = _block_full(cfg, lp, x, angles, positions,
+                                  causal=causal)
+        return (constrain_batch(x), aux + a), cache if want_cache else None
 
+    def unit_fn(carry, lp):
+        if not has_dense_sub:
+            return layer_fn(carry, lp)
+        x, aux = carry
+        x = constrain_batch(x)
+        x, c1, a1 = _block_full(cfg, lp["dense_sub"], x, angles,
+                                positions, causal=causal)
+        x, c2, a2 = _block_full(cfg, lp["moe_sub"], x, angles,
+                                positions, causal=causal)
+        x = constrain_batch(x)
+        return (x, aux + a1 + a2), (c1, c2) if want_cache else None
+
+    lead_fn = layer_fn
     if cfg.remat_layers:
+        lead_fn = jax.checkpoint(layer_fn, prevent_cse=False)
         unit_fn = jax.checkpoint(unit_fn, prevent_cse=False)
 
-    carry0 = (constrain_batch(x), jnp.zeros((), jnp.float32))
+    carry = (constrain_batch(x), moe_lib.aux_zeros(cfg))
+    if "dense_layers" in params:
+        carry, _ = _over_stack(cfg, lead_fn, carry, params["dense_layers"],
+                               want_cache=False)
+    (x, aux), caches = _over_stack(cfg, unit_fn, carry, params["layers"],
+                                   want_cache=want_cache)
+    return x, aux, caches
+
+
+def _refuse_leading_dense(cfg):
+    if cfg.first_k_dense:
+        raise NotImplementedError(
+            f"{cfg.name}: prefill and decode do not cover the leading "
+            "dense layers (first_k_dense); the model trains only")
+
+
+def _over_stack(cfg, fn, carry, stack, *, want_cache: bool):
+    """``fn`` over the leading axis of a stacked layer tree: one scan, or
+    with ``scan_layers=False`` (analysis / tiny-model) a Python loop."""
     if cfg.scan_layers:
-        (x, aux), caches = jax.lax.scan(unit_fn, carry0, params["layers"])
-        return x, aux, caches
-    # unrolled (analysis / tiny-model) path: python loop over units
-    nu = cfg.num_layers // per_unit
-    carry = carry0
+        return jax.lax.scan(fn, carry, stack)
+    n = jax.tree_util.tree_leaves(stack)[0].shape[0]
     cache_list = []
-    for i in range(nu):
-        lp = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
-        carry, ys = unit_fn(carry, lp)
+    for i in range(n):
+        lp = jax.tree_util.tree_map(lambda a: a[i], stack)
+        carry, ys = fn(carry, lp)
         cache_list.append(ys)
-    x, aux = carry
     caches = None
     if want_cache:
         caches = jax.tree_util.tree_map(
             lambda *xs: jnp.stack(xs), *cache_list)
-    return x, aux, caches
+    return carry, caches
 
 
 def _logits(params, cfg, x):
@@ -273,6 +299,7 @@ def _cache_len(cfg, seq_len: int) -> int:
 def init_cache(cfg: ModelConfig, batch_size: int, seq_len: int,
                dtype=None) -> Dict[str, Any]:
     """Zeroed decode cache covering ``seq_len`` positions."""
+    _refuse_leading_dense(cfg)
     dtype = dtype or jnp.dtype(cfg.dtype)
     L = cfg.num_layers
     per_unit, has_dense_sub = _unit_layout(cfg)
@@ -422,6 +449,7 @@ def _block_decode(cfg, lp, x, angles, cache_slices, pos):
 def decode_step(params, cfg: ModelConfig, cache, tokens, *, batch=None,
                 dtype=None):
     """Decode ONE token.  tokens (B, 1).  Returns (logits, new cache)."""
+    _refuse_leading_dense(cfg)
     dtype = dtype or jnp.dtype(cfg.dtype)
     b = tokens.shape[0]
     pos = cache["pos"]
@@ -500,9 +528,15 @@ def train_loss(params, cfg: ModelConfig, batch, *, dtype=None):
         mask = batch.get("loss_mask")
     s, n = xent_loss(logits, labels, mask)
     loss = s / jnp.maximum(n, 1.0)
-    if cfg.kind == MOE:
+    if _switch_aux(cfg):
         loss = loss + cfg.moe.router_aux_weight * aux
     return loss
+
+
+def _switch_aux(cfg) -> bool:
+    """Whether the MoE layers' second output is the Switch aux loss (the
+    capacity router); ``noaux_tc`` routing has none and counts pairs."""
+    return cfg.kind == MOE and cfg.moe.routing != "noaux_tc"
 
 
 def train_loss_sum(params, cfg: ModelConfig, batch, *, dtype=None):
@@ -517,6 +551,11 @@ def train_loss_sum(params, cfg: ModelConfig, batch, *, dtype=None):
     (aux is still computed over padded rows — all-zero token rows — so
     a PADDED MoE client deviates by the aux share of those rows;
     docs/lm_federation.md lists it as a known limit).
+
+    With ``noaux_tc`` routing there is no aux loss, and a third item,
+    ``{"expert_tokens": (E_held,)}``, gives the routed (token, held
+    expert) pairs of every MoE layer (padded rows included), which the
+    federated round sums into its record.
     """
     logits, aux = forward_train(params, cfg, batch, dtype=dtype)
     if cfg.kind == AUDIO:
@@ -530,6 +569,8 @@ def train_loss_sum(params, cfg: ModelConfig, batch, *, dtype=None):
     if doc_mask is not None:
         mask = mask * doc_mask[..., None]
     s, n = xent_loss(logits, labels, mask)
-    if cfg.kind == MOE:
+    if _switch_aux(cfg):
         s = s + cfg.moe.router_aux_weight * aux * n
+    elif cfg.kind == MOE:
+        return s, n, {"expert_tokens": aux}
     return s, n

@@ -20,7 +20,9 @@ DRAW = "round/draw"           # the jitted cohort index draws (host fill: reads)
 # enqueue of their gather from the clients' rows on the device, 0: their
 # numpy fill on the host
 GATHER = "round/gather"
-DISPATCH = "round/dispatch"   # the fused call's enqueue; the copy follows
+# the fused call's enqueue (the copy follows); count ``scan`` 1 when the
+# round scans its clients one by one, 0 when it vmaps them
+DISPATCH = "round/dispatch"
 FETCH = "round/fetch"         # scalar and loss reads, the round's record
 HOST_SPANS = (DRAW, GATHER, DISPATCH, FETCH)
 
@@ -30,7 +32,15 @@ LOCAL_UPDATE = "local_update"
 AGGREGATE = "aggregate"           # the Eq. (2) combine and the server step
 TRANSFORM_SCOPES = tuple(f"transform/{n}"
                          for n in ("dp", "topk", "secure", "precision"))
-DEVICE_SCOPES = (LOCAL_UPDATE,) + TRANSFORM_SCOPES + (AGGREGATE,)
+# inside a language model's layers (models/layers/moe.py
+# ``held_moe_apply``, models/layers/attention.py ``mla_full``)
+MOE_ROUTE = "moe/route"       # router, top-k, the pairs' sort and combine
+MOE_EXPERTS = "moe/experts"   # the grouped product over the held experts
+MOE_SHARED = "moe/shared"     # the shared experts
+ATTENTION_MLA = "attention/mla"
+MODEL_SCOPES = (MOE_ROUTE, MOE_EXPERTS, MOE_SHARED, ATTENTION_MLA)
+DEVICE_SCOPES = (LOCAL_UPDATE,) + TRANSFORM_SCOPES + (AGGREGATE,) \
+    + MODEL_SCOPES
 
 
 def span(name: str, **counts):

@@ -191,6 +191,30 @@ def test_fed_combine_matches_ref(k, d, bk, bd, dtype, rng):
                                rtol=0, atol=2e-6)
 
 
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_running_sum_matches_ref_and_the_combine(backend, rng):
+    """The client-scan round's running Eq. (2) numerator, one client at
+    a time, against its oracle at every step; over the cohort and
+    normalised it is the stacked combine.  Zero-weight rows hold NaN."""
+    from repro.kernels import ops
+    k, d = 5, 300
+    x = rng.standard_normal((k, d)).astype(np.float32)
+    w = rng.uniform(0, 2, k).astype(np.float32)
+    w[[1, 3]] = 0.0
+    x[w == 0.0] = np.nan
+    x, w = jnp.asarray(x), jnp.asarray(w)
+    acc, want = {"a": jnp.zeros((d,))}, jnp.zeros((d,))
+    for i in range(k):
+        acc = ops.fed_weighted_accumulate(acc, {"a": x[i]}, w[i],
+                                          backend=backend)
+        want = ref.fed_accumulate_ref(want, x[i], w[i])
+        np.testing.assert_allclose(np.asarray(acc["a"]), np.asarray(want),
+                                   rtol=0, atol=2e-6)
+    np.testing.assert_allclose(
+        np.asarray(acc["a"] / jnp.sum(w)),
+        np.asarray(ref.fed_combine_ref(x, w)), rtol=0, atol=2e-6)
+
+
 def test_fed_combine_empty_and_all_padded(rng):
     """All-zero weights -> zero combine (guarded denominator, matching
     aggregate_stacked); an empty K=0 cohort -> zeros without tracing a

@@ -76,3 +76,47 @@ def test_resident_corpus_placement(where, device, monkeypatch, corpus8):
     fed.run(rounds=3)
     assert seen == [0, device, device]
     assert set(eng.trace_counts.values()) == {1}
+
+
+def test_the_model_scopes_reach_the_round_graph():
+    """The held-expert layer and MLA put their four scopes on the ops of
+    a language model's loss and its gradient."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.models import transformer as tfm
+    base = get_config("moonlight-16b-a3b")
+    cfg = dataclasses.replace(
+        base.reduced(), moe=dataclasses.replace(base.reduced().moe,
+                                                ep_size=2))
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    toks = jnp.zeros((1, 8), jnp.int32)
+
+    def loss(p):
+        return tfm.train_loss_sum(p, cfg, {"tokens": toks,
+                                           "labels": toks})[0]
+    hlo = jax.jit(jax.grad(loss)).lower(params).as_text(debug_info=True)
+    assert set(spans.MODEL_SCOPES) <= set(spans.DEVICE_SCOPES)
+    for name in spans.MODEL_SCOPES:
+        assert name in hlo, name
+
+
+@pytest.mark.parametrize("free, scan", [(None, 0), (4096, 1)])
+def test_dispatch_counts_the_schedule(free, scan, monkeypatch, corpus8):
+    """``round/dispatch`` carries ``scan``: 1 when the cohort does not fit
+    the device and the round scans its clients, else 0."""
+    from repro.api import Federation
+    if free is not None:
+        used = 1 << 30
+        monkeypatch.setattr(
+            type(jax.devices()[0]), "memory_stats",
+            lambda self: {"bytes_limit": used + free, "bytes_in_use": used})
+    seen = []
+    real = spans.span
+
+    def span(name, **counts):
+        if name == spans.DISPATCH:
+            seen.append(counts["scan"])
+        return real(name, **counts)
+    monkeypatch.setattr(spans, "span", span)
+    Federation.from_spec(tiny_spec(), corpus=corpus8).run(rounds=2)
+    assert seen == [scan, scan]
